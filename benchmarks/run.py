@@ -45,6 +45,9 @@ def main() -> None:
         help="where BENCH_*.json perf baselines are written",
     )
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     # Machine-readable perf baselines: modules listed here append structured
     # records which land in BENCH_<module>.json next to the CSV on stdout,

@@ -59,9 +59,7 @@ class _BatchedSlots(NamedTuple):
 
 def _canonical_keys(rngs: jax.Array) -> jax.Array:
     """Accept typed PRNG key arrays or raw uint32 key data."""
-    if hasattr(jax.dtypes, "prng_key") and jnp.issubdtype(
-        rngs.dtype, jax.dtypes.prng_key
-    ):
+    if jnp.issubdtype(rngs.dtype, jax.dtypes.prng_key):
         return jax.random.key_data(rngs)
     return rngs
 

@@ -36,6 +36,7 @@ implementations, so engines stay evaluator-agnostic and
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Optional
 
 import jax
@@ -133,6 +134,22 @@ class Evaluator:
     """
 
     env: Optional[Environment] = None
+
+    def weights(self) -> Pytree:
+        """Arrays the evaluator closes over (model weights), or ``None``.
+
+        A jitted caller passes them in as an argument and traces under
+        :meth:`bound`; closed over, they would be baked into the compiled
+        program as constants (a copy of every weight per compile).
+        """
+        return None
+
+    @contextlib.contextmanager
+    def bound(self, weights: Pytree):
+        """Trace with ``weights`` (the jitted caller's argument) standing in
+        for :meth:`weights`."""
+        del weights
+        yield
 
     def init_aux(self, root_states: Pytree, prefix: tuple) -> Pytree:
         del root_states, prefix
@@ -432,6 +449,18 @@ class ModelEvaluator(Evaluator):
         self.reward_params = reward_params
         self.forward_fn = forward_fn
         self.value_fn = value_fn
+
+    def weights(self) -> Pytree:
+        return (self.params, self.reward_params)
+
+    @contextlib.contextmanager
+    def bound(self, weights: Pytree):
+        saved = self.weights()
+        self.params, self.reward_params = weights
+        try:
+            yield
+        finally:
+            self.params, self.reward_params = saved
 
     def _position_logits(self, params, cfg, tokens, lengths) -> jax.Array:
         """Logits at each slot's current position — ONE forward for [N]."""
@@ -817,7 +846,7 @@ class CachedModelEvaluator(ModelEvaluator):
             cache.pop("len")
             ring[key] = {
                 "cache": cache,
-                "logits": jnp.zeros((c, mcfg.vocab_size), jnp.float32),
+                "logits": jnp.zeros((c, mcfg.vocab_size), mcfg.dtype),
             }
         return ring
 
@@ -1402,7 +1431,7 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
         }
         for key, _, mcfg in self._branches():
             ring[key] = {
-                "logits": jnp.zeros((c, mcfg.vocab_size), jnp.float32),
+                "logits": jnp.zeros((c, mcfg.vocab_size), mcfg.dtype),
             }
         return ring
 

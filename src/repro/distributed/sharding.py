@@ -38,14 +38,11 @@ Pytree = Any
 
 
 def _mesh_axis_sizes(mesh) -> dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape)) if isinstance(
-        mesh, Mesh
-    ) else dict(mesh.shape)
+    return dict(mesh.shape)
 
 
 def data_axes(mesh) -> tuple[str, ...]:
-    names = mesh.axis_names if hasattr(mesh, "axis_names") else tuple(mesh.shape)
-    return tuple(a for a in ("pod", "data") if a in names)
+    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
 def logical_spec(mesh, *axes) -> P:
@@ -64,44 +61,28 @@ def logical_spec(mesh, *axes) -> P:
 
 
 def ambient_abstract_mesh():
-    """The ambient abstract mesh, or ``None`` on JAX versions without the
-    ``get_abstract_mesh`` API (pre-0.5) — constraints degrade to no-ops."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
+    """The ambient abstract mesh (empty outside a mesh context)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    Newer JAX takes ``(axis_sizes, axis_names, axis_types=...)``; pre-0.5
-    releases (no ``AxisType``) take a single ``((name, size), ...)`` tuple.
-    Spec logic downstream only reads ``.shape`` / ``.axis_names``, which both
-    forms provide.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """A device-free ``AbstractMesh`` with every axis ``Auto``-typed."""
     return jax.sharding.AbstractMesh(
         tuple(axis_sizes), tuple(axis_names),
-        axis_types=(axis_type.Auto,) * len(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
     )
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where it exists; on older JAX the ``Mesh`` object itself
-    is the context manager.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def constrain(x: jax.Array, *axes) -> jax.Array:
     """with_sharding_constraint against the ambient abstract mesh (no-op
     outside a mesh context, so model code stays mesh-agnostic)."""
     mesh = ambient_abstract_mesh()
-    if mesh is None or not getattr(mesh, "axis_names", ()):  # unset mesh
+    if not mesh.axis_names:  # unset mesh
         return x
     spec = logical_spec(mesh, *axes)
     # Drop axes that don't divide the corresponding dim.

@@ -3,12 +3,17 @@
 Frontier expansion scores all ``A`` candidate children of a settled leaf in
 one forward — the queries differ only in their final token, so the shared
 prefix K/V should stream through VMEM ONCE for the whole candidate set, not
-once per candidate.  The kernel is the split-KV decode kernel widened to an
-``[A, Hq, D]`` query tile: prefix blocks fold into the online-softmax state
-exactly as before (now per candidate), and the last grid step folds in the
-speculative tail — each candidate's own K/V entry, which lives OUTSIDE the
-cache — under a caller-supplied ``[A, A]`` tree mask (identity for a flat
-frontier: candidate ``i`` attends only tail entry ``i``).
+once per candidate.  The kernel is the split-KV decode kernel widened to
+``A·group`` query rows per KV head: prefix blocks fold into the
+online-softmax state exactly as before (now per candidate), and the last
+grid step folds in the speculative tail — each candidate's own K/V entry,
+which lives OUTSIDE the cache — under a caller-supplied ``[A, A]`` tree mask
+(identity for a flat frontier: candidate ``i`` attends only tail entry
+``i``).
+
+Query rows are laid out ``[Hkv, A·group, D]`` (candidate-major within each
+KV head), so, as in the decode kernel, every contraction is a 2-D matmul
+against one head's lane-aligned column block of the ``[N, Hkv·D]`` K/V view.
 
 The paged variant walks the page table via scalar prefetch, identical to
 ``_paged_decode_kernel``: only the addressing differs, the math is shared.
@@ -24,112 +29,84 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from .decode_attention import (
+    COMPILER_PARAMS,
+    fold_heads,
+    fold_prefix_block,
+    init_state,
+    normalized,
+    softmax_scratch,
+)
 
 
 def _tree_decode_kernel(
     len_ref,    # [B] i32 (SMEM) — per-batch valid KV prefix length
-    q_ref,      # [A, Hq, D]
-    k_ref,      # [block_k, Hkv, D]
-    v_ref,      # [block_k, Hkv, D]
-    ks_ref,     # [A, Hkv, D] — speculative tail keys for this row
-    vs_ref,     # [A, Hkv, D]
-    mask_ref,   # [A, A] i32 — tree mask (nonzero = attend)
-    o_ref,      # [A, Hq, D]
-    m_scr,      # [A, Hq, 1] f32
-    l_scr,      # [A, Hq, 1] f32
-    acc_scr,    # [A, Hq, D] f32
+    q_ref,      # [Hkv, A·group, D]
+    k_ref,      # [block_k, Hkv·D]
+    v_ref,      # [block_k, Hkv·D]
+    ks_ref,     # [A, Hkv·D] — speculative tail keys for this row
+    vs_ref,     # [A, Hkv·D]
+    mask_ref,   # [A·group, A] i32 — tree mask row per query (nonzero: attend)
+    o_ref,      # [Hkv, A·group, D]
+    m_scr,      # [Hkv, A·group, 1] f32
+    l_scr,      # [Hkv, A·group, 1] f32
+    acc_scr,    # [Hkv, A·group, D] f32
     *,
     scale: float,
     block_k: int,
     n_kv: int,
-    group: int,
 ):
     ki = pl.program_id(1)
-    kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_state(m_scr, l_scr, acc_scr)
 
-    @pl.when(ki * block_k < kv_len)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)                    # [A, Hq, D]
-        k = k_ref[...].astype(jnp.float32)                    # [bk, Hkv, D]
-        v = v_ref[...].astype(jnp.float32)
-        a, hq, _ = q.shape
-        bk = k.shape[0]
-        kg = jnp.repeat(k, group, axis=1)                     # [bk, Hq, D]
-        s = jnp.einsum("ahd,jhd->ahj", q, kg) * scale         # [A, Hq, bk]
-        kv_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (a, hq, bk), 2
-        )
-        valid = kv_pos < kv_len
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        vg = jnp.repeat(v, group, axis=1)                     # [bk, Hq, D]
-        acc_scr[...] = acc_scr[...] * alpha + jnp.einsum("ahj,jhd->ahd", p, vg)
-        m_scr[...] = m_new
+    fold_prefix_block(
+        ki, len_ref[pl.program_id(0)], q_ref, k_ref, v_ref,
+        m_scr, l_scr, acc_scr, scale=scale, block_k=block_k,
+    )
 
     @pl.when(ki == n_kv - 1)
     def _tail_and_finalize():
         # Fold the speculative tail (A extra K/V entries, masked by the tree
         # mask) into the online-softmax state, then normalize.  Runs after
         # the prefix fold of this block (pl.when bodies run in order).
-        q = q_ref[...].astype(jnp.float32)                    # [A, Hq, D]
-        ks = jnp.repeat(
-            ks_ref[...].astype(jnp.float32), group, axis=1
-        )                                                     # [A, Hq, D]
-        vs = jnp.repeat(vs_ref[...].astype(jnp.float32), group, axis=1)
-        st = jnp.einsum("ahd,jhd->ahj", q, ks) * scale        # [A, Hq, A]
-        attend = mask_ref[...] != 0                           # [A, A]
-        st = jnp.where(attend[:, None, :], st, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(st, axis=-1, keepdims=True))
-        p = jnp.where(attend[:, None, :], jnp.exp(st - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc_scr[...] * alpha + jnp.einsum("ahj,jhd->ahd", p, vs)
-        o_ref[...] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+        fold_heads(q_ref, ks_ref, vs_ref, mask_ref[...] != 0,
+                   m_scr, l_scr, acc_scr, scale=scale)
+        o_ref[...] = normalized(l_scr, acc_scr, o_ref.dtype)
 
 
-def _paged_tree_decode_kernel(
-    table_ref,  # [B, n_pages] i32 (scalar prefetch) — consumed by index maps
-    len_ref,    # [B] i32 (scalar prefetch)
-    q_ref,
-    k_ref,      # [block_size, Hkv, D] — one page, fetched via the page table
-    v_ref,
-    ks_ref,
-    vs_ref,
-    mask_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    scale: float,
-    block_k: int,
-    n_kv: int,
-    group: int,
-):
+def _paged_tree_decode_kernel(table_ref, len_ref, *refs, **kw):
     del table_ref
-    _tree_decode_kernel(
-        len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, o_ref,
-        m_scr, l_scr, acc_scr,
-        scale=scale, block_k=block_k, n_kv=n_kv, group=group,
+    _tree_decode_kernel(len_ref, *refs, **kw)
+
+
+def _rows(q, hkv):
+    """``[B, A, Hq, D]`` -> ``[B, Hkv, A·group, D]`` (candidate-major)."""
+    b, a, hq, d = q.shape
+    g = hq // hkv
+    return q.reshape(b, a, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, a * g, d
     )
 
 
-def _prep_mask(tree_mask, a):
+def _unrows(o, a):
+    """Inverse of :func:`_rows`."""
+    b, hkv, r, d = o.shape
+    g = r // a
+    return o.reshape(b, hkv, a, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, a, hkv * g, d
+    )
+
+
+def _row_mask(tree_mask, a, group):
+    """``[A, A]`` tree mask (None = identity) -> one row per query row."""
     if tree_mask is None:
-        return jnp.eye(a, dtype=jnp.int32)
-    return jnp.asarray(tree_mask).astype(jnp.int32)
+        mask = jnp.eye(a, dtype=jnp.int32)
+    else:
+        mask = jnp.asarray(tree_mask).astype(jnp.int32)
+    return jnp.repeat(mask, group, axis=0)
 
 
 def tree_decode_attention_fwd(
@@ -147,48 +124,39 @@ def tree_decode_attention_fwd(
     b, a, hq, d = q.shape
     _, s, hkv, _ = k_cache.shape
     group = hq // hkv
+    r = a * group
     block_k = min(block_k, s)
     assert s % block_k == 0
     n_kv = s // block_k
-    scale = 1.0 / math.sqrt(d)
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
-    mask = _prep_mask(tree_mask, a)
 
     kernel = functools.partial(
-        _tree_decode_kernel, scale=scale, block_k=block_k, n_kv=n_kv,
-        group=group,
+        _tree_decode_kernel, scale=1.0 / math.sqrt(d), block_k=block_k,
+        n_kv=n_kv,
     )
+    kv = pl.BlockSpec((None, block_k, hkv * d), lambda bi, ki: (bi, ki, 0))
+    tail = pl.BlockSpec((None, a, hkv * d), lambda bi, ki: (bi, 0, 0))
+    rows = pl.BlockSpec((None, hkv, r, d), lambda bi, ki: (bi, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
+        name="tree_decode_attention",
         grid=(b, n_kv),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, a, hq, d), lambda bi, ki: (bi, 0, 0, 0)),
-            pl.BlockSpec((None, block_k, hkv, d), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec((None, block_k, hkv, d), lambda bi, ki: (bi, ki, 0, 0)),
-            pl.BlockSpec((None, a, hkv, d), lambda bi, ki: (bi, 0, 0, 0)),
-            pl.BlockSpec((None, a, hkv, d), lambda bi, ki: (bi, 0, 0, 0)),
-            pl.BlockSpec((a, a), lambda bi, ki: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM), rows, kv, kv, tail, tail,
+            pl.BlockSpec((r, a), lambda bi, ki: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, a, hq, d), lambda bi, ki: (bi, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, a, hq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((a, hq, 1), jnp.float32),
-            pltpu.VMEM((a, hq, 1), jnp.float32),
-            pltpu.VMEM((a, hq, d), jnp.float32),
-        ],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
+        scratch_shapes=softmax_scratch(hkv, r, d),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        **(
-            {}
-            if interpret
-            else {
-                "compiler_params": pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-            }
-        ),
-    )(lens, q, k_cache, v_cache, k_spec, v_spec, mask)
-    return out
+    )(
+        lens, _rows(q, hkv),
+        k_cache.reshape(b, s, hkv * d), v_cache.reshape(b, s, hkv * d),
+        k_spec.reshape(b, a, hkv * d), v_spec.reshape(b, a, hkv * d),
+        _row_mask(tree_mask, a, group),
+    )
+    return _unrows(out, a)
 
 
 def paged_tree_decode_attention_fwd(
@@ -215,60 +183,46 @@ def paged_tree_decode_attention_fwd(
     p, block_size, hkv, _ = pool_k.shape
     n_pages = page_table.shape[1]
     group = hq // hkv
-    scale = 1.0 / math.sqrt(d)
+    r = a * group
     lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
     table = jnp.clip(page_table.astype(jnp.int32), 0, p - 1)
-    mask = _prep_mask(tree_mask, a)
 
     kernel = functools.partial(
-        _paged_tree_decode_kernel, scale=scale, block_k=block_size,
-        n_kv=n_pages, group=group,
+        _paged_tree_decode_kernel, scale=1.0 / math.sqrt(d),
+        block_k=block_size, n_kv=n_pages,
+    )
+    page = pl.BlockSpec(
+        (None, block_size, hkv * d),
+        lambda bi, pi, tab, lens: (tab[bi, pi], 0, 0),
+    )
+    tail = pl.BlockSpec(
+        (None, a, hkv * d), lambda bi, pi, tab, lens: (bi, 0, 0)
+    )
+    rows = pl.BlockSpec(
+        (None, hkv, r, d), lambda bi, pi, tab, lens: (bi, 0, 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_pages),
         in_specs=[
-            pl.BlockSpec(
-                (None, a, hq, d), lambda bi, pi, tab, lens: (bi, 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (None, block_size, hkv, d),
-                lambda bi, pi, tab, lens: (tab[bi, pi], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, block_size, hkv, d),
-                lambda bi, pi, tab, lens: (tab[bi, pi], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, a, hkv, d), lambda bi, pi, tab, lens: (bi, 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (None, a, hkv, d), lambda bi, pi, tab, lens: (bi, 0, 0, 0)
-            ),
-            pl.BlockSpec((a, a), lambda bi, pi, tab, lens: (0, 0)),
+            rows, page, page, tail, tail,
+            pl.BlockSpec((r, a), lambda bi, pi, tab, lens: (0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (None, a, hq, d), lambda bi, pi, tab, lens: (bi, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((a, hq, 1), jnp.float32),
-            pltpu.VMEM((a, hq, 1), jnp.float32),
-            pltpu.VMEM((a, hq, d), jnp.float32),
-        ],
+        out_specs=rows,
+        scratch_shapes=softmax_scratch(hkv, r, d),
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_tree_decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, a, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-        **(
-            {}
-            if interpret
-            else {
-                "compiler_params": pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-            }
-        ),
-    )(table, lens, q, pool_k, pool_v, k_spec, v_spec, mask)
-    return out
+    )(
+        table, lens, _rows(q, hkv),
+        pool_k.reshape(p, block_size, hkv * d),
+        pool_v.reshape(p, block_size, hkv * d),
+        k_spec.reshape(b, a, hkv * d), v_spec.reshape(b, a, hkv * d),
+        _row_mask(tree_mask, a, group),
+    )
+    return _unrows(out, a)

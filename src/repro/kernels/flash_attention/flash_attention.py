@@ -119,6 +119,7 @@ def flash_attention_fwd(
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(b * hq, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -142,16 +143,10 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
-        **(
-            {}
-            if interpret
-            else {
-                "compiler_params": pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "parallel", "arbitrary")
-                )
-            }
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
+        interpret=interpret,
     )(qt, kt, vt)
 
     return jnp.swapaxes(out.reshape(b, hq, sq, d), 1, 2)

@@ -98,6 +98,7 @@ def ssd_scan_fwd(
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     y = pl.pallas_call(
         kernel,
+        name="ssd_scan",
         grid=(b * h, nc),
         in_specs=[
             pl.BlockSpec((None, chunk, p), lambda bh, ci: (bh, ci, 0)),
@@ -108,15 +109,9 @@ def ssd_scan_fwd(
         out_specs=pl.BlockSpec((None, chunk, p), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, p), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
-        **(
-            {}
-            if interpret
-            else {
-                "compiler_params": pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-            }
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
+        interpret=interpret,
     )(xr, dar, Bmat, Cmat)
     return jnp.moveaxis(y.reshape(b, h, s, p), 1, 2)

@@ -138,6 +138,7 @@ def tree_select_fwd(
     )
     act, score = pl.pallas_call(
         kernel,
+        name="tree_select",
         grid=(bp // block_b,),
         in_specs=[
             pl.BlockSpec((block_b, a), lambda i: (i, 0)),

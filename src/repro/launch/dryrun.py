@@ -27,6 +27,7 @@ from typing import Optional
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.cells import SHAPES, all_cells, build_cell, skip_reason
 from repro.launch.mesh import make_production_mesh, make_test_mesh
 
@@ -340,6 +341,7 @@ def main() -> None:
         help="comma list of cfg overrides, e.g. num_heads=48,loss_chunk=512",
     )
     args = ap.parse_args()
+    use_compile_cache()
 
     overrides = None
     if args.override:

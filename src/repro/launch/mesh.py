@@ -10,12 +10,9 @@ import jax
 
 
 def _mk(shape, axes):
-    # axis_types only exists on newer JAX; pre-0.5 meshes are untyped.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kw = {} if axis_type is None else {
-        "axis_types": (axis_type.Auto,) * len(axes)
-    }
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

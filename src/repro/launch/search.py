@@ -28,6 +28,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import SearchSpec, build_searcher, play_episode
 from repro.distributed import constrain_search_batch
 from repro.envs import make_bandit_tree, make_random_mdp, make_tap_game
@@ -63,6 +64,7 @@ def main() -> None:
                     help="wave: barrier per wave; async: slot-level "
                          "interleaving (refill the instant a rollout settles)")
     args = ap.parse_args()
+    use_compile_cache()
 
     env = make_env(args.env)
     spec = SearchSpec(

@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_reduced
 from repro.models import init_params
 from repro.serving import ServeConfig, ServingEngine
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=48)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.smoke else get_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))

@@ -22,6 +22,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_reduced
 from repro.models import abstract_params, init_params
 from repro.training import (
@@ -48,6 +49,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:

@@ -59,9 +59,11 @@ class ModelConfig:
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: jnp.dtype = jnp.bfloat16
-    # Attention implementation: 'xla' (chunked online-softmax jnp; used by the
-    # dry-run since Pallas cannot compile on the CPU backend) or 'pallas'.
-    attn_impl: str = "xla"
+    # Attention implementation: 'auto' picks the Pallas kernels on a TPU
+    # backend and the chunked online-softmax jnp path elsewhere (see
+    # layers._use_pallas); 'pallas' or 'xla' forces one.  The SSD scan kernel
+    # runs only under an explicit 'pallas': it does not lower for the TPU.
+    attn_impl: str = "auto"
     attn_chunk: int = 1024
     remat: bool = True
     # scan_layers=False unrolls the layer loop (used by the dry-run roofline

@@ -9,6 +9,7 @@ multi-pod dry-run compiles (Pallas cannot target the CPU backend).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -303,7 +304,49 @@ def attention_qkv(p, cfg, x, positions, rope: bool = True):
 
 
 def _use_pallas(cfg) -> bool:
-    return getattr(cfg, "attn_impl", "xla") == "pallas"
+    """Whether attention runs through the Pallas kernels.
+
+    ``attn_impl='auto'`` follows the platform: the kernels on a TPU backend,
+    the jnp paths (their test oracles) elsewhere.  ``'pallas'`` and
+    ``'xla'`` force one side (interpret-mode parity tests, the CPU dry-run).
+    """
+    if cfg.attn_impl == "auto":
+        return jax.default_backend() == "tpu"
+    return cfg.attn_impl == "pallas"
+
+
+def _flash_block(sq: int) -> int | None:
+    """Largest q/kv block (at most 256) that tiles ``sq`` and that the TPU
+    compiler can lay out — a multiple of 8 or the whole length — or None
+    when there is none (e.g. ``sq=300`` would need 4-row blocks)."""
+    bq = min(256, sq)
+    while sq % bq:
+        bq //= 2
+    return bq if bq == sq or bq % 8 == 0 else None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_causal(q, k, v, block, chunk):
+    """Causal attention through the flash kernel.  The kernel has a forward
+    pass only, so the gradient is ``chunked_attention``'s, recomputed from
+    q, k and v (training differentiates through this path)."""
+    from ..kernels.flash_attention.ops import flash_attention
+
+    return flash_attention(q, k, v, causal=True, block_q=block, block_k=block)
+
+
+def _flash_causal_fwd(q, k, v, block, chunk):
+    return _flash_causal(q, k, v, block, chunk), (q, k, v)
+
+
+def _flash_causal_bwd(block, chunk, res, g):
+    _, vjp = jax.vjp(
+        functools.partial(chunked_attention, causal=True, chunk=chunk), *res
+    )
+    return vjp(g)
+
+
+_flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
 
 
 def attention_block(
@@ -318,12 +361,14 @@ def attention_block(
 ):
     """Full attention block; returns (out, new_cache).
 
-    ``cfg.attn_impl == 'pallas'`` routes the no-cache causal path through the
-    flash-attention TPU kernel and single-token decode — scalar or per-slot
-    vector cache lengths — through the split-KV decode kernel (interpret
-    mode on CPU); paths the kernels don't cover (chunked prefill with
-    offsets) fall back to the jnp oracle — which the kernels are verified
-    against bit-for-bit in tests/test_kernels.py.
+    When :func:`_use_pallas` holds (on a TPU by default) the no-cache causal
+    path runs the flash-attention kernel (gradient from ``chunked_attention``;
+    lengths :func:`_flash_block` cannot tile stay on ``chunked_attention``)
+    and single-token decode — scalar or
+    per-slot vector cache lengths, dense or paged — the split-KV decode
+    kernels.  Chunked prefill with an offset (cached prefill and catch-up
+    chunks) has no kernel and always runs ``chunked_attention``, the jnp
+    path the kernels are checked against in tests/test_kernels.py.
     """
     q, k, v = attention_qkv(p, cfg, x, positions, rope=rope)
     if cache is not None and "table" in cache:
@@ -357,14 +402,9 @@ def attention_block(
         out = out.reshape(b, s, cfg.num_heads * cfg.head_dim) @ p["wo"]
         return out, new_cache
     if cache is None:
-        if _use_pallas(cfg) and causal and q.shape[1] == k.shape[1]:
-            from ..kernels.flash_attention.ops import flash_attention
-
-            sq = q.shape[1]
-            bq = max(1, min(256, sq))
-            while sq % bq:
-                bq //= 2
-            out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bq)
+        block = _flash_block(q.shape[1])
+        if _use_pallas(cfg) and causal and block is not None:
+            out = _flash_causal(q, k, v, block, cfg.attn_chunk)
         else:
             out = chunked_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
         new_cache = None
@@ -545,13 +585,7 @@ def moe_block(p, cfg, x):
     from ..distributed.sharding import ambient_abstract_mesh
 
     mesh = ambient_abstract_mesh()
-    try:
-        axes = dict(mesh.shape)
-    except (AttributeError, TypeError):
-        # No ambient mesh (None) or a mesh whose .shape isn't dict-able
-        # (older JAX AbstractMesh): fall back to the unsharded local path.
-        axes = {}
-    tp = axes.get("model", 1)
+    tp = dict(mesh.shape).get("model", 1)
     if tp > 1 and cfg.num_experts % tp == 0:
         out, aux = _moe_block_sharded(p, cfg, x, mesh)
         if "shared" in p:

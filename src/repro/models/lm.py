@@ -203,7 +203,7 @@ def _ssm_body(cfg, bp, x, cache, return_cache=False):
 
 def _embed_inputs(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
     tokens = batch["tokens"]
-    x = params["embed"][tokens]
+    x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.family == "vlm" and "patch_embeds" in batch:
         patches = batch["patch_embeds"].astype(x.dtype)
         x = jnp.concatenate([patches, x], axis=1)
@@ -705,7 +705,7 @@ def decode_step(params, cfg: ModelConfig, token, cache) -> tuple[jax.Array, Pytr
     ``cache["len"]`` may be a scalar (uniform batch) or a per-slot ``[B]``
     vector (ragged decode: continuous batching, async search slots) — each
     slot writes and attends at its own position, through the Pallas decode
-    kernel when ``cfg.attn_impl == 'pallas'``.
+    kernel where ``layers._use_pallas(cfg)`` holds (on a TPU by default).
     """
     token = token.reshape(token.shape[0], 1)
     logits, cache = _step_with_cache(params, cfg, {"tokens": token}, cache)

@@ -156,7 +156,7 @@ def ssm_block(p, cfg, u, *, cache=None, return_cache: bool = False):
         xh = x.reshape(b, s, h, pdim)
         xdt = xh * dt[..., None]
         dA = dt * A
-        if getattr(cfg, "attn_impl", "xla") == "pallas" and not return_cache:
+        if cfg.attn_impl == "pallas" and not return_cache:
             # TPU kernel path (kernels/ssd_scan); the cache-producing prefill
             # needs h_final, which the fused kernel keeps in VMEM — fall back.
             from ..kernels.ssd_scan.ops import ssd_scan as _ssd_kernel

@@ -416,16 +416,20 @@ class SearchService:
         carry = engine.evict(carry, jnp.arange(B, dtype=jnp.int32))
         self._engine = engine
         self._carry = carry
-        self._segment = jax.jit(
+        # Every serving program takes the evaluator's weights as its first
+        # argument (see Evaluator.weights): closed over, they would be
+        # compiled in as constants.
+        self._weights = self.evaluator.weights()
+        self._segment = self._jit(
             lambda c: engine.run_segment(c, self.ticks_per_round)
         )
-        self._result_fn = jax.jit(engine.result)
+        self._result_fn = self._jit(engine.result)
         # The service always admits/evicts ONE row per call: `rows` keeps a
         # fixed [1] shape, so these trace exactly once — a variable-size
         # admission batch would recompile the whole splice (prefill included)
         # for every distinct batch size it ever saw.
-        self._admit_fn = jax.jit(engine.admit)
-        self._evict_fn = jax.jit(engine.evict)
+        self._admit_fn = self._jit(engine.admit)
+        self._evict_fn = self._jit(engine.evict)
         if self.fused:
             # Device-resident ring: stage() keeps a fixed [1] request shape
             # per call (same single-signature discipline as admit/evict);
@@ -433,12 +437,34 @@ class SearchService:
             # so the host pays ONE dispatch + ONE sync per segment.
             self._ring = engine.init_ring(roots, self.ring_capacity)
             self._row_req_dev = jnp.full((B,), -1, jnp.int32)
-            self._stage_fn = jax.jit(engine.stage)
-            self._serve_fn = jax.jit(
+            self._stage_fn = self._jit(engine.stage)
+            self._serve_fn = self._jit(
                 lambda c, g, q: engine.serve_segment(
                     c, g, q, self.ticks_per_segment
                 )
             )
+
+    def compiled_segment_text(self) -> str:
+        """Compiled HLO text of the segment program :meth:`poll` runs (the
+        fused ring's ``serve_segment``, else ``run_segment``), for checking
+        which kernels the served path holds."""
+        self._ensure_engine()
+        if self.fused:
+            fn, args = self._serve_fn, (self._carry, self._ring,
+                                        self._row_req_dev)
+        else:
+            fn, args = self._segment, (self._carry,)
+        return fn.lower(self._weights, *args).compile().as_text()
+
+    def _jit(self, fn):
+        """``jax.jit`` of ``fn`` taking the evaluator's weights first."""
+        evaluator = self.evaluator
+
+        def call(weights, *args):
+            with evaluator.bound(weights):
+                return fn(*args)
+
+        return jax.jit(call)
 
     def _free_pool_blocks(self) -> Optional[int]:
         """Free blocks in the paged evaluator's pool (None when dense)."""
@@ -490,7 +516,9 @@ class SearchService:
         if done_rows:
             # One device->host transfer for the whole batch; per-request
             # rows are host-side slices.
-            res = jax.tree.map(np.asarray, self._result_fn(carry))
+            res = jax.tree.map(
+                np.asarray, self._result_fn(self._weights, carry)
+            )
             for b in done_rows:
                 req_id = self._row_req[b]
                 # Host-side slicing of an already-fetched numpy tree — no
@@ -510,9 +538,8 @@ class SearchService:
                 # variable-shape alternative was PR 8's 30x regression), and
                 # done_rows is bounded by the small host-side batch B.
                 # reprolint: disable=JX002
-                self._carry = self._evict_fn(
-                    self._carry, jnp.asarray([b], jnp.int32)
-                )
+                row = jnp.asarray([b], jnp.int32)
+                self._carry = self._evict_fn(self._weights, self._carry, row)
         return fresh
 
     def _admit_queued(self, settled: Optional[np.ndarray] = None) -> int:
@@ -544,7 +571,7 @@ class SearchService:
             if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
                 key = jax.random.key_data(key)
             self._carry = self._admit_fn(
-                self._carry, jnp.asarray([b], jnp.int32),
+                self._weights, self._carry, jnp.asarray([b], jnp.int32),
                 self._root_rows([prompt]), key[None],
             )
             self._row_req[b] = req_id
@@ -578,7 +605,7 @@ class SearchService:
         # serves admission (one device sync per round, not three).
         self._admit_queued(settled)
         if any(r is not None for r in self._row_req):
-            self._carry, t, busy = self._segment(self._carry)
+            self._carry, t, busy = self._segment(self._weights, self._carry)
             self.stats.ticks += int(t)
             self.stats.busy_tree_ticks += int(busy)
         self.stats.host_rounds += 1
@@ -605,14 +632,17 @@ class SearchService:
             if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
                 key = jax.random.key_data(key)
             self._carry, self._ring = self._stage_fn(
-                self._carry, self._ring, self._root_rows([prompt]),
-                key[None], jnp.asarray([req_id], jnp.int32),
+                self._weights, self._carry, self._ring,
+                self._root_rows([prompt]), key[None],
+                jnp.asarray([req_id], jnp.int32),
             )
             self._ring_free -= 1
         staged = self.ring_capacity - self._ring_free
         fresh = {}
         if staged > 0 or self._inflight > 0:
-            out = self._serve_fn(self._carry, self._ring, self._row_req_dev)
+            out = self._serve_fn(
+                self._weights, self._carry, self._ring, self._row_req_dev
+            )
             self._carry, self._ring, self._row_req_dev = out[:3]
             comp, t, busy = out[3:]
             oom = self._carry[7]["oom"] if self.paged else 0

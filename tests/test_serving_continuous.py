@@ -130,6 +130,16 @@ def test_submit_poll_drain_incremental(tiny_lm):
     assert svc.stats.completed == len(PROMPTS)
 
 
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "host_paced"])
+def test_compiled_segment_text(tiny_lm, fused):
+    """The segment program ``poll`` runs can be compiled on demand for
+    inspection, before any request, and serving carries on after it."""
+    svc = _service(tiny_lm, paged=False, fused=fused)
+    assert "HloModule" in svc.compiled_segment_text()
+    rows = svc.serve(PROMPTS[:2])
+    assert [0 <= int(r.action) < 4 for r in rows] == [True, True]
+
+
 def test_continuous_serving_needs_async_engine(tiny_lm):
     cfg, params = tiny_lm
     svc = SearchService(

@@ -21,8 +21,7 @@ from repro.models import abstract_params
 
 @pytest.fixture(scope="module")
 def mesh():
-    # AbstractMesh stand-in for spec logic (no devices needed); the compat
-    # constructor papers over the pre-0.5 AbstractMesh signature.
+    # AbstractMesh stand-in for spec logic (no devices needed).
     return abstract_mesh((16, 16), ("data", "model"))
 
 
