@@ -37,7 +37,7 @@ Two claims under test:
   the host-paced poll path (requests/s, slot-idle fraction, host rounds);
   the ``serving_fused`` rows report the device-resident ring path
   (admission/eviction inside the jitted segment — one host sync per
-  segment) with its host-round reduction and mean ring occupancy; the
+  segment) with its host-round reduction; the
   ``serving_speedup`` rows compare the fused drain against the one-shot
   path serving the same workload in sequential ``B``-sized batches.
 
@@ -348,9 +348,8 @@ def _serving_rows(
     * ``serving_fused`` — the device-resident ring path (``fused=True``,
       ring sized to the workload): requests/s, ``host_rounds`` (one per
       ``ticks_per_segment`` segment — admission/eviction happen inside
-      the jitted ``while_loop``), host rounds per drained request, and
-      mean ring occupancy, beside the host-paced ``host_rounds`` for the
-      reduction ratio.
+      the jitted ``while_loop``) and host rounds per drained request,
+      beside the host-paced ``host_rounds`` for the reduction ratio.
     * ``serving_speedup`` — the fused drain vs the same workload in
       sequential one-shot ``B``-batches.
 
@@ -416,10 +415,6 @@ def _serving_rows(
         )
         t_fused, fst0, fst = timed_drain(fsvc)
         host_rounds_fused = fst.host_rounds - fst0.host_rounds
-        ring_occ = (
-            (fst.ring_occupancy_sum - fst0.ring_occupancy_sum)
-            / max(host_rounds_fused, 1)
-        )
 
         # One-shot baseline: the same workload in sequential B-batches,
         # each blocking on its slowest search (same compiled program as
@@ -455,7 +450,6 @@ def _serving_rows(
                 "requests_per_sec": n_req / t_fused,
                 "host_rounds": host_rounds_fused,
                 "host_rounds_per_request": host_rounds_fused / n_req,
-                "ring_occupancy": ring_occ,
                 "host_paced_host_rounds": host_rounds_poll,
                 "host_rounds_reduction": (
                     host_rounds_poll / max(host_rounds_fused, 1)
@@ -477,8 +471,7 @@ def _serving_rows(
         out.append(row(
             f"serving_fused_{mode}_B{batch}", t_fused,
             f"{n_req / t_fused:.2f} req/s; {host_rounds_fused} host rounds "
-            f"({host_rounds_poll / max(host_rounds_fused, 1):.1f}x fewer); "
-            f"ring occ {ring_occ:.2f}",
+            f"({host_rounds_poll / max(host_rounds_fused, 1):.1f}x fewer)",
         ))
         out.append(row(
             f"serving_speedup_{mode}_B{batch}", 0.0,

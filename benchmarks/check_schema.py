@@ -47,8 +47,8 @@ REQUIRED_FIELDS: dict[str, set[str]] = {
     },
     "serving_fused": {
         "requests", "requests_per_sec", "host_rounds",
-        "host_rounds_per_request", "ring_occupancy",
-        "host_paced_host_rounds", "host_rounds_reduction",
+        "host_rounds_per_request", "host_paced_host_rounds",
+        "host_rounds_reduction",
     },
     "serving_speedup": {
         "requests", "speedup", "sequential_seconds", "fused_seconds",

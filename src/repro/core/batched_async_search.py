@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from ..envs.base import Environment
 from . import batched_tree as btree
 from .async_search import EXPAND, FREE, SIM, tick_snapshot
-from .evaluators import Evaluator, RolloutEvaluator
+from .evaluators import CATCH_UP, REFILL_CACHE, Evaluator, RolloutEvaluator
 from .batched_search import (
     _canonical_keys,
     _expansion_actions,
@@ -69,6 +69,17 @@ from .batched_tree import init_batched_tree
 from .wu_uct import SearchConfig, SearchResult
 
 Pytree = Any
+
+# The stages of a master tick and of the in-loop serving round, as
+# ``jax.named_scope``s: each name reaches the ``op_name`` metadata of the ops
+# it covers, so a device trace can be timed by stage (a fusion takes the
+# scope of its root op).  The evaluator's two refill stages are named in
+# :mod:`repro.core.evaluators`.
+SELECT = "select"            # tree policy: traversal, expansion, in-flight marks
+DECODE = "decode"            # the evaluator's tick over all B·W slots
+SETTLE = "settle"            # finalize expanded children, back up returns
+SERVE_ROUND = "serve_round"  # in-loop harvest, eviction and ring admission
+TICK_SCOPES = (SELECT, REFILL_CACHE, CATCH_UP, DECODE, SETTLE, SERVE_ROUND)
 
 
 class _BatchedAsyncSlots(NamedTuple):
@@ -108,9 +119,12 @@ class Completions(NamedTuple):
 
     ``count`` rows are valid; each is the :class:`SearchResult` snapshot of
     one request taken at the tick its tree settled, tagged with the
-    ``req_id`` the host staged it under.  Capacity is ``B + ring_capacity``
-    — everything in flight plus everything staged can complete within one
-    segment, so a segment can never overflow its own buffer.
+    ``req_id`` the host staged it under, and with ``settle_tick``, the
+    number of the segment's ticks run when the row was found settled (its
+    ``ticks`` counts back to the tick it was admitted at).  Capacity is
+    ``B + ring_capacity`` — everything in flight plus everything staged can
+    complete within one segment, so a segment can never overflow its own
+    buffer.
     """
 
     req_id: jax.Array      # i32[C_out]
@@ -121,6 +135,7 @@ class Completions(NamedTuple):
     max_o: jax.Array       # f32[C_out]
     overflowed: jax.Array  # bool[C_out]
     ticks: jax.Array       # i32[C_out]
+    settle_tick: jax.Array  # i32[C_out]
     count: jax.Array       # i32[]
 
 
@@ -250,34 +265,52 @@ class BatchedAsyncEngine:
 
         def body(j, c):
             tree, slots, rng, t_launch, t_done, aux, fr_hits = c
-            rng, k_t, k_e = _split_each(rng, 3)
-            want = (slots.kind[:, j] == FREE) & (t_launch < T)
+            with jax.named_scope(SELECT):
+                rng, k_t, k_e = _split_each(rng, 3)
+                want = (slots.kind[:, j] == FREE) & (t_launch < T)
 
-            nodes = traverse_batched(tree, k_t, cfg, self.use_kernel)
-            kids = tree.children[bidx, nodes]
-            n_tried = jnp.sum((kids >= 0).astype(jnp.int32), axis=1)
-            is_term = tree.terminal[bidx, nodes]
-            at_depth = tree.depth[bidx, nodes] >= cfg.max_depth
-            needs_exp = (
-                jnp.logical_not(is_term)
-                & jnp.logical_not(at_depth)
-                & (n_tried < self.width)
-            )
-            act = _expansion_actions(tree, nodes, k_e, self._exp_cfg)
-            tree, child, reserved = btree.reserve_children(
-                tree, nodes, act, mask=want & needs_exp
-            )
-            needs_exp = needs_exp & reserved
-            sim_node = jnp.where(needs_exp, child, nodes).astype(jnp.int32)
-            tree = _mark_in_flight(tree, sim_node, cfg, mask=want)
+                nodes = traverse_batched(tree, k_t, cfg, self.use_kernel)
+                kids = tree.children[bidx, nodes]
+                n_tried = jnp.sum((kids >= 0).astype(jnp.int32), axis=1)
+                is_term = tree.terminal[bidx, nodes]
+                at_depth = tree.depth[bidx, nodes] >= cfg.max_depth
+                needs_exp = (
+                    jnp.logical_not(is_term)
+                    & jnp.logical_not(at_depth)
+                    & (n_tried < self.width)
+                )
+                act = _expansion_actions(tree, nodes, k_e, self._exp_cfg)
+                tree, child, reserved = btree.reserve_children(
+                    tree, nodes, act, mask=want & needs_exp
+                )
+                needs_exp = needs_exp & reserved
+                sim_node = jnp.where(needs_exp, child, nodes).astype(jnp.int32)
+                tree = _mark_in_flight(tree, sim_node, cfg, mask=want)
 
-            # Terminal hit: settle instantly, slot stays FREE (the paper
-            # counts it as a completed simulation with return 0).
-            tree = _settle(
-                tree, sim_node, jnp.zeros((B,), jnp.float32), cfg,
-                mask=want & is_term,
-            )
-            parent_state = btree.get_state(tree, nodes)
+                # Terminal hit: settle instantly, slot stays FREE (the paper
+                # counts it as a completed simulation with return 0).
+                tree = _settle(
+                    tree, sim_node, jnp.zeros((B,), jnp.float32), cfg,
+                    mask=want & is_term,
+                )
+                parent_state = btree.get_state(tree, nodes)
+                slots = self._set_slot(
+                    slots,
+                    j,
+                    want,
+                    kind=jnp.where(
+                        is_term, FREE, jnp.where(needs_exp, EXPAND, SIM)
+                    ).astype(jnp.int32),
+                    sim_node=sim_node,
+                    act=act,
+                    state=parent_state,
+                    rollout_done=tree.terminal[bidx, sim_node],
+                    acc=jnp.zeros((B,), jnp.float32),
+                    disc=jnp.ones((B,), jnp.float32),
+                    steps=jnp.zeros((B,), jnp.int32),
+                )
+                t_launch = t_launch + want.astype(jnp.int32)
+                t_done = t_done + (want & is_term).astype(jnp.int32)
             # Re-sync the evaluator's slot caches: slot column j of every
             # tree lives at flat row b·W + j of the aux pool.
             aux, hit = self.evaluator.refill_aux(
@@ -285,23 +318,6 @@ class BatchedAsyncEngine:
                 want & jnp.logical_not(is_term),
             )
             fr_hits = fr_hits + hit.astype(jnp.int32)
-            slots = self._set_slot(
-                slots,
-                j,
-                want,
-                kind=jnp.where(
-                    is_term, FREE, jnp.where(needs_exp, EXPAND, SIM)
-                ).astype(jnp.int32),
-                sim_node=sim_node,
-                act=act,
-                state=parent_state,
-                rollout_done=tree.terminal[bidx, sim_node],
-                acc=jnp.zeros((B,), jnp.float32),
-                disc=jnp.ones((B,), jnp.float32),
-                steps=jnp.zeros((B,), jnp.int32),
-            )
-            t_launch = t_launch + want.astype(jnp.int32)
-            t_done = t_done + (want & is_term).astype(jnp.int32)
             return tree, slots, rng, t_launch, t_done, aux, fr_hits
 
         return jax.lax.fori_loop(0, W, body, carry)
@@ -326,7 +342,8 @@ class BatchedAsyncEngine:
             args = self.constrain(args)
         # aux stays outside `constrain`: model-cache leaves lead with the
         # layer axis, not the slot axis the hook shards.
-        out, aux = self.evaluator.tick(self.cfg, *args, aux)
+        with jax.named_scope(DECODE):
+            out, aux = self.evaluator.tick(self.cfg, *args, aux)
         if self.constrain is not None:
             out = self.constrain(out)
         out = jax.tree.map(lambda x: x.reshape((B, W) + x.shape[1:]), out)
@@ -369,7 +386,8 @@ class BatchedAsyncEngine:
             )
             return tree, slots, t_done + fin.astype(jnp.int32)
 
-        return jax.lax.fori_loop(0, self.W, body, carry)
+        with jax.named_scope(SETTLE):
+            return jax.lax.fori_loop(0, self.W, body, carry)
 
     def alive(self, carry) -> jax.Array:
         """bool[B] — trees still short of their simulation budget."""
@@ -386,17 +404,21 @@ class BatchedAsyncEngine:
             (tree, slots, rng, t_launch, t_done, aux, fr_hits)
         )
         max_o = jnp.maximum(max_o, tree.O[:, 0])
+        attended = self.evaluator.attended_positions(aux)
         slots, r_edge, done_edge, aux = self._tick(slots, k_tick, aux)
         tree, slots, t_done = self._settle_finished(
             (tree, slots, t_done), r_edge, done_edge
         )
         return (
             tree, slots, rng, t_launch, t_done, ticks + 1, max_o, aux, fr_hits
-        )
+        ), attended
 
     def step(self, carry):
         """One master tick with finished trees frozen — the same per-lane
         masking ``vmap`` would apply to the single engine's while_loop.
+        Returns ``(carry, attended)``: the new carry, and the key/value
+        positions the tick's decode step attended over all ``B·W`` slots
+        (``Evaluator.attended_positions``; 0 without a model cache).
 
         The evaluator aux rides outside the freeze: its leaves don't lead
         with ``[B]`` (model caches lead with the layer axis), and a finished
@@ -422,13 +444,13 @@ class BatchedAsyncEngine:
                 jnp.int32
             )
         )
-        new = self._master_iter((carry[0], masked) + carry[2:])
+        new, attended = self._master_iter((carry[0], masked) + carry[2:])
         # aux rides outside the freeze (above); the per-tree frontier-hit
         # counter rides after it and freezes with a plain where — its hits
         # are already masked by ``want``, so dead lanes never advance.
         return _freeze_done(alive, new[:-2], carry[:-2]) + (
             new[-2], jnp.where(alive, new[-1], carry[-1]),
-        )
+        ), attended
 
     # ------------------------------------------------------------------
     # Request lifecycle (the serving layer's surface)
@@ -511,24 +533,24 @@ class BatchedAsyncEngine:
     def run_segment(self, carry, num_ticks: int):
         """Up to ``num_ticks`` master ticks; stops early when all settled.
 
-        Returns ``(carry, ticks_run, busy_tree_ticks)`` — the occupancy
-        numerator/denominator the serving layer turns into its slot-idle
-        fraction (a settled row's ``W`` slots idle for the rest of the
-        segment; ``busy_tree_ticks`` counts row-ticks that searched).
+        Returns ``(carry, ticks_run, busy_tree_ticks, attended)`` — the
+        occupancy numerator/denominator the serving layer turns into its
+        slot-idle fraction (a settled row's ``W`` slots idle for the rest of
+        the segment; ``busy_tree_ticks`` counts row-ticks that searched),
+        and the key/value positions the segment's decode steps attended.
         """
         def cond(c):
-            carry, t, _ = c
+            carry, t, _, _ = c
             return (t < num_ticks) & jnp.any(self.alive(carry))
 
         def body(c):
-            carry, t, busy = c
+            carry, t, busy, att = c
             busy = busy + jnp.sum(self.alive(carry).astype(jnp.int32))
-            return self.step(carry), t + 1, busy
+            carry, seen = self.step(carry)
+            return carry, t + 1, busy, att + seen
 
-        carry, t, busy = jax.lax.while_loop(
-            cond, body, (carry, jnp.int32(0), jnp.int32(0))
-        )
-        return carry, t, busy
+        zero = jnp.int32(0)
+        return jax.lax.while_loop(cond, body, (carry, zero, zero, zero))
 
     def result(self, carry) -> SearchResult:
         """``SearchResult[B]`` snapshot (meaningful on settled rows)."""
@@ -632,8 +654,9 @@ class BatchedAsyncEngine:
         row_req = jnp.where(mask, ring.req_id[slot], row_req)
         return carry, ring._replace(aux=ring_aux), row_req
 
-    def _serve_round(self, carry, ring: RequestRing, row_req, comp):
-        """One in-loop harvest + admit round (traceable).
+    def _serve_round(self, carry, ring: RequestRing, row_req, comp, t):
+        """One in-loop harvest + admit round (traceable), after ``t`` ticks
+        of the segment.
 
         Settled rows holding a request (``row_req >= 0``) append their
         :meth:`result` snapshot to the completion buffer and release their
@@ -660,6 +683,9 @@ class BatchedAsyncEngine:
                 res.overflowed, mode="drop"
             ),
             ticks=comp.ticks.at[dst].set(res.ticks, mode="drop"),
+            settle_tick=comp.settle_tick.at[dst].set(
+                jnp.full((self.B,), t, jnp.int32), mode="drop"
+            ),
             count=comp.count + jnp.sum(done.astype(jnp.int32)),
         )
         aux = self.evaluator.evict_aux_to_ring(carry[7], done, self.W)
@@ -688,7 +714,8 @@ class BatchedAsyncEngine:
         settled), then one frozen-masked master tick.  A final round after
         the loop harvests rows that settled on the last tick.  Exits early
         when every row is idle and the ring is empty.  Returns
-        ``(carry, ring, row_req, completions, ticks_run, busy_tree_ticks)``.
+        ``(carry, ring, row_req, completions, ticks_run, busy_tree_ticks,
+        attended)`` (see :meth:`run_segment`).
         """
         ccap = self.B + ring.req_id.shape[0]
         proto = self.result(carry)
@@ -701,43 +728,46 @@ class BatchedAsyncEngine:
             action=buf(proto.action), root_n=buf(proto.root_n),
             root_v=buf(proto.root_v), tree_size=buf(proto.tree_size),
             max_o=buf(proto.max_o), overflowed=buf(proto.overflowed),
-            ticks=buf(proto.ticks), count=jnp.int32(0),
+            ticks=buf(proto.ticks), settle_tick=buf(proto.ticks),
+            count=jnp.int32(0),
         )
 
-        def maybe_round(carry, ring, row_req, comp):
+        def maybe_round(carry, ring, row_req, comp, t):
             settled = self.settled(carry)
             want = jnp.any(settled & (row_req >= 0)) | (
                 (ring.count > 0) & jnp.any(settled)
             )
-            return jax.lax.cond(
-                want,
-                self._serve_round,
-                lambda c, g, q, m: (c, g, q, m),
-                carry, ring, row_req, comp,
-            )
+            with jax.named_scope(SERVE_ROUND):
+                return jax.lax.cond(
+                    want,
+                    self._serve_round,
+                    lambda c, g, q, m, _: (c, g, q, m),
+                    carry, ring, row_req, comp, t,
+                )
 
         def cond(c):
-            carry, ring, row_req, _, t, _ = c
+            carry, ring, row_req, _, t, _, _ = c
             more = jnp.any(self.alive(carry)) | (ring.count > 0)
             return (t < num_ticks) & more
 
         def body(c):
-            carry, ring, row_req, comp, t, busy = c
+            carry, ring, row_req, comp, t, busy, att = c
             carry, ring, row_req, comp = maybe_round(
-                carry, ring, row_req, comp
+                carry, ring, row_req, comp, t
             )
             busy = busy + jnp.sum(self.alive(carry).astype(jnp.int32))
-            return self.step(carry), ring, row_req, comp, t + 1, busy
+            carry, seen = self.step(carry)
+            return carry, ring, row_req, comp, t + 1, busy, att + seen
 
-        carry, ring, row_req, comp, t, busy = jax.lax.while_loop(
-            cond, body,
-            (carry, ring, row_req, comp, jnp.int32(0), jnp.int32(0)),
+        zero = jnp.int32(0)
+        carry, ring, row_req, comp, t, busy, att = jax.lax.while_loop(
+            cond, body, (carry, ring, row_req, comp, zero, zero, zero),
         )
         # Harvest rows that settled on the loop's last tick without paying
         # a masked tick for them (admission here also primes the next
         # segment's first tick).
-        carry, ring, row_req, comp = maybe_round(carry, ring, row_req, comp)
-        return carry, ring, row_req, comp, t, busy
+        carry, ring, row_req, comp = maybe_round(carry, ring, row_req, comp, t)
+        return carry, ring, row_req, comp, t, busy, att
 
     # ------------------------------------------------------------------
     # One-shot runs (the pre-existing API)
@@ -748,7 +778,7 @@ class BatchedAsyncEngine:
         if trace_ticks > 0:
             def scan_body(carry, _):
                 alive = self.alive(carry)
-                new = self.step(carry)
+                new, _ = self.step(carry)
                 ev_len = self.evaluator.aux_len(new[7])
                 if ev_len is not None:
                     ev_len = ev_len.reshape(self.B, self.W)
@@ -762,7 +792,7 @@ class BatchedAsyncEngine:
             )
             return self.result(final), trace
         final = jax.lax.while_loop(
-            lambda c: jnp.any(self.alive(c)), self.step, init
+            lambda c: jnp.any(self.alive(c)), lambda c: self.step(c)[0], init
         )
         return self.result(final)
 

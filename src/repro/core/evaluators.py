@@ -49,6 +49,11 @@ Pytree = Any
 # Slot phases, shared with the async engines (async_search re-exports them).
 FREE, EXPAND, SIM = 0, 1, 2
 
+# ``jax.named_scope``s of the two refill stages of a master tick (the engine
+# names the others, see ``batched_async_search.TICK_SCOPES``).
+REFILL_CACHE = "refill_cache"  # slot-cache row gather and scatter
+CATCH_UP = "catch_up"          # re-decode of each refilled slot's suffix
+
 
 def slot_accounting(gamma, kind, nxt, state, r, done, rollout_done, acc, disc,
                     steps):
@@ -235,6 +240,12 @@ class Evaluator:
     def aux_len(self, aux) -> Optional[jax.Array]:
         del aux
         return None
+
+    def attended_positions(self, aux) -> jax.Array:
+        """Key/value positions the next decode step over ``aux`` attends,
+        summed over every slot (``i32[]``; 0 without a model cache)."""
+        del aux
+        return jnp.int32(0)
 
     def aux_last_logits(self, aux) -> Optional[jax.Array]:
         """Most recent per-slot policy logits ``[N, V]``, when the evaluator
@@ -645,6 +656,7 @@ class CachedModelEvaluator(ModelEvaluator):
             out.append(("rew", self.reward_params, self.reward_cfg))
         return out
 
+    @jax.named_scope(REFILL_CACHE)
     def _take_rows(self, aux, rows):
         def branch(b):
             if b == ():
@@ -661,6 +673,7 @@ class CachedModelEvaluator(ModelEvaluator):
             "rew": branch(aux["rew"]),
         }
 
+    @jax.named_scope(REFILL_CACHE)
     def _put_rows(self, aux, rows, sub):
         def branch(b, sb):
             if b == ():
@@ -912,6 +925,7 @@ class CachedModelEvaluator(ModelEvaluator):
             }
         return out, ring_aux
 
+    @jax.named_scope(CATCH_UP)
     def _catch_up(self, sub, target, r, s_max):
         """Re-decode each row's divergent suffix in batched ragged chunks.
 
@@ -958,6 +972,13 @@ class CachedModelEvaluator(ModelEvaluator):
 
     def aux_len(self, aux) -> Optional[jax.Array]:
         return aux["len"]
+
+    def attended_positions(self, aux) -> jax.Array:
+        """Every slot decodes and attends ``min(len, max_len - 1) + 1``
+        positions (the paged twin counts one more than it reads for a slot
+        that feeds no token)."""
+        s_max = aux["tokens"].shape[-1]
+        return jnp.sum(jnp.minimum(aux["len"], s_max - 1) + 1)
 
     def aux_last_logits(self, aux) -> Optional[jax.Array]:
         return aux["pol"]["logits"]
@@ -1085,6 +1106,7 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
 
     # -- aux structure helpers ---------------------------------------------
 
+    @jax.named_scope(REFILL_CACHE)
     def _take_rows(self, aux, rows):
         def branch(b):
             if b == ():
@@ -1101,6 +1123,7 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
             "rew": branch(aux["rew"]),
         }
 
+    @jax.named_scope(REFILL_CACHE)
     def _put_rows(self, aux, rows, sub):
         def branch(b, sb):
             if b == ():
@@ -1554,6 +1577,7 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
             len=jnp.where(fm, 0, aux["len"]),
         )
 
+    @jax.named_scope(CATCH_UP)
     def _paged_catch_up(self, sub, target, r, s_max):
         """Chunked divergent-suffix re-decode over paged rows.
 
@@ -1751,6 +1775,7 @@ class _FrontierMixin:
         aux["fr"] = self._fr_init(aux)
         return aux
 
+    @jax.named_scope(REFILL_CACHE)
     def _take_rows(self, aux, rows):
         sub = super()._take_rows(aux, rows)
         fr = aux["fr"]
@@ -1770,6 +1795,7 @@ class _FrontierMixin:
         }
         return sub
 
+    @jax.named_scope(REFILL_CACHE)
     def _put_rows(self, aux, rows, sub):
         out = super()._put_rows(aux, rows, sub)
         fr, sfr = aux["fr"], sub["fr"]

@@ -27,17 +27,20 @@ Two serving shapes:
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import heapq
 import json
 import os
+import time
 import warnings
 from pathlib import Path
-from typing import Iterable, Optional, Sequence
+from typing import Iterable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import SearchResult, SearchSpec, build_searcher
 from ..core.api import as_search_config
@@ -165,9 +168,14 @@ class ServeStats:
     #: host-paced path, one per fused ``serve_segment`` on the ring path —
     #: the quantity the device-resident loop exists to shrink.
     host_rounds: int = 0
-    #: Sum over host rounds of the ring occupancy at segment dispatch
-    #: (fused path only); :attr:`ring_occupancy` is the mean.
-    ring_occupancy_sum: int = 0
+    #: Key/value positions the decode steps attended, summed over ticks and
+    #: over all ``B·W`` slots (``Evaluator.attended_positions``).
+    attended_positions: int = 0
+    #: Sums over answered requests (fused path) of the host-clock waits of
+    #: :class:`RequestTimeline`, in microseconds: submit to admission into
+    #: a row, and settle to the answer on the host.
+    queue_wait_us: int = 0
+    answer_wait_us: int = 0
 
     @property
     def slot_idle_frac(self) -> float:
@@ -176,12 +184,27 @@ class ServeStats:
             return 0.0
         return 1.0 - self.busy_tree_ticks / cap
 
-    @property
-    def ring_occupancy(self) -> float:
-        """Mean staged requests per fused host round (0 when host-paced)."""
-        if self.host_rounds == 0:
-            return 0.0
-        return self.ring_occupancy_sum / self.host_rounds
+
+class RequestTimeline(NamedTuple):
+    """One answered request on the fused path, in ``time.perf_counter``
+    seconds, with the ticks of the service's master-tick count at which its
+    row was admitted and settled.
+
+    ``submit``, ``staged`` (the ``stage`` dispatch) and ``answered`` (the
+    end of the fetch that brought the answer) are read on the host.  The
+    device reports ticks only; ``admitted`` and ``settled`` map them to the
+    host clock by linear interpolation over the host interval of the
+    segment that ran them, from its dispatch to the end of its fetch, so
+    they are good to about one tick.
+    """
+
+    submit: float
+    staged: float
+    admitted: float
+    settled: float
+    answered: float
+    admit_tick: int
+    settle_tick: int
 
 
 class SearchService:
@@ -318,6 +341,12 @@ class SearchService:
         self._row_req_dev = None
         self._ring_free = self.ring_capacity
         self._inflight = 0
+        # Request timelines (fused path): host times of submit and staging
+        # until the answer, then one RequestTimeline; and per segment run,
+        # (first tick, ticks run, dispatch time, end of fetch).
+        self._times: dict = {}
+        self._timeline: dict = {}
+        self._segments: list = []
 
     # ------------------------------------------------------------------
     # Root-state packing
@@ -421,15 +450,16 @@ class SearchService:
         # compiled in as constants.
         self._weights = self.evaluator.weights()
         self._segment = self._jit(
-            lambda c: engine.run_segment(c, self.ticks_per_round)
+            lambda c: engine.run_segment(c, self.ticks_per_round),
+            "run_segment",
         )
-        self._result_fn = self._jit(engine.result)
+        self._result_fn = self._jit(engine.result, "result")
         # The service always admits/evicts ONE row per call: `rows` keeps a
         # fixed [1] shape, so these trace exactly once — a variable-size
         # admission batch would recompile the whole splice (prefill included)
         # for every distinct batch size it ever saw.
-        self._admit_fn = self._jit(engine.admit)
-        self._evict_fn = self._jit(engine.evict)
+        self._admit_fn = self._jit(engine.admit, "admit")
+        self._evict_fn = self._jit(engine.evict, "evict")
         if self.fused:
             # Device-resident ring: stage() keeps a fixed [1] request shape
             # per call (same single-signature discipline as admit/evict);
@@ -437,11 +467,12 @@ class SearchService:
             # so the host pays ONE dispatch + ONE sync per segment.
             self._ring = engine.init_ring(roots, self.ring_capacity)
             self._row_req_dev = jnp.full((B,), -1, jnp.int32)
-            self._stage_fn = self._jit(engine.stage)
+            self._stage_fn = self._jit(engine.stage, "stage")
             self._serve_fn = self._jit(
                 lambda c, g, q: engine.serve_segment(
                     c, g, q, self.ticks_per_segment
-                )
+                ),
+                "serve_segment",
             )
 
     def compiled_segment_text(self) -> str:
@@ -456,14 +487,17 @@ class SearchService:
             fn, args = self._segment, (self._carry,)
         return fn.lower(self._weights, *args).compile().as_text()
 
-    def _jit(self, fn):
-        """``jax.jit`` of ``fn`` taking the evaluator's weights first."""
+    def _jit(self, fn, name: str):
+        """``jax.jit`` of ``fn`` taking the evaluator's weights first, named
+        ``name`` (the program shows as ``jit_<name>`` in compiled HLO and
+        in a device trace's modules)."""
         evaluator = self.evaluator
 
         def call(weights, *args):
             with evaluator.bound(weights):
                 return fn(*args)
 
+        call.__name__ = call.__qualname__ = name
         return jax.jit(call)
 
     def _free_pool_blocks(self) -> Optional[int]:
@@ -491,6 +525,8 @@ class SearchService:
         validate_prompts([prompt], self.max_len)
         req_id = self._next_req_id
         self._next_req_id += 1
+        if self.fused:
+            self._times[req_id] = [time.perf_counter()]
         if key is None:
             key = jax.random.fold_in(self._base_key, req_id)
         heapq.heappush(
@@ -605,16 +641,21 @@ class SearchService:
         # serves admission (one device sync per round, not three).
         self._admit_queued(settled)
         if any(r is not None for r in self._row_req):
-            self._carry, t, busy = self._segment(self._weights, self._carry)
+            self._carry, t, busy, att = self._segment(
+                self._weights, self._carry
+            )
             self.stats.ticks += int(t)
             self.stats.busy_tree_ticks += int(busy)
+            self.stats.attended_positions += int(att)
         self.stats.host_rounds += 1
         return fresh
 
     def _poll_fused(self) -> dict:
         """One fused round: refill the ring, run one segment, drain
         completions.  The only device syncs are the paged pool budget (when
-        staging) and the single post-segment fetch."""
+        staging) and the single post-segment fetch.  Host spans
+        (``serve.stage``, ``serve.dispatch``, ``serve.fetch``,
+        ``serve.harvest``) go to the profiler's trace when one runs."""
         budget = self._free_pool_blocks()
         while self._queue and self._ring_free > 0:
             _, req_id, prompt, key = self._queue[0]
@@ -624,57 +665,90 @@ class SearchService:
                     break  # wait for pages to free (admit in order)
                 budget -= need
             heapq.heappop(self._queue)
-            # Deliberate per-request staging dispatch: a fixed [1]-shape
-            # request keeps the jitted stage at ONE compiled signature (the
-            # variable-shape alternative was PR 8's 30x regression), and
-            # the loop is bounded by the small host-side ring capacity.
-            # reprolint: disable=JX002
-            if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-                key = jax.random.key_data(key)
-            self._carry, self._ring = self._stage_fn(
-                self._weights, self._carry, self._ring,
-                self._root_rows([prompt]), key[None],
-                jnp.asarray([req_id], jnp.int32),
-            )
+            self._times[req_id].append(time.perf_counter())
+            with TraceAnnotation("serve.stage", req_id=req_id):
+                # Deliberate per-request staging dispatch: a fixed [1]-shape
+                # request keeps the jitted stage at ONE compiled signature
+                # (a variable shape recompiles the prefill for every batch
+                # size, a 30x slowdown once measured), and the loop is
+                # bounded by the small host-side ring capacity.
+                # reprolint: disable=JX002
+                if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+                    key = jax.random.key_data(key)
+                self._carry, self._ring = self._stage_fn(
+                    self._weights, self._carry, self._ring,
+                    self._root_rows([prompt]), key[None],
+                    jnp.asarray([req_id], jnp.int32),
+                )
             self._ring_free -= 1
         staged = self.ring_capacity - self._ring_free
         fresh = {}
         if staged > 0 or self._inflight > 0:
-            out = self._serve_fn(
-                self._weights, self._carry, self._ring, self._row_req_dev
-            )
+            t_dispatch = time.perf_counter()
+            with TraceAnnotation("serve.dispatch"):
+                out = self._serve_fn(
+                    self._weights, self._carry, self._ring, self._row_req_dev
+                )
             self._carry, self._ring, self._row_req_dev = out[:3]
-            comp, t, busy = out[3:]
+            comp, t, busy, att = out[3:]
             oom = self._carry[7]["oom"] if self.paged else 0
-            comp, t, busy, count_after, oom = jax.device_get(
-                (comp, t, busy, self._ring.count, oom)
-            )
+            with TraceAnnotation("serve.fetch"):
+                comp, t, busy, att, count_after, oom = jax.device_get(
+                    (comp, t, busy, att, self._ring.count, oom)
+                )
+            t_end = time.perf_counter()
             if self.paged:
                 self.evaluator._maybe_raise(oom)
+            first, t = self.stats.ticks, int(t)
+            if t > 0:
+                self._segments.append((first, t, t_dispatch, t_end))
             n = int(comp.count)
-            for i in range(n):
-                req_id = int(comp.req_id[i])
-                # Host-side slicing of the already-fetched completion buffer
-                # (device_get above) — no device dispatch in this loop.
-                # reprolint: disable=JX002
-                row = SearchResult(
-                    action=comp.action[i], root_n=comp.root_n[i],
-                    root_v=comp.root_v[i], tree_size=comp.tree_size[i],
-                    dup_selections=np.float32(0.0), max_o=comp.max_o[i],
-                    overflowed=comp.overflowed[i], ticks=comp.ticks[i],
-                )
-                self._results[req_id] = row
-                fresh[req_id] = row
+            with TraceAnnotation("serve.harvest"):
+                for i in range(n):
+                    req_id = int(comp.req_id[i])
+                    # Host-side slicing of the already-fetched completion
+                    # buffer (device_get above) — no device dispatch here.
+                    # reprolint: disable=JX002
+                    row = SearchResult(
+                        action=comp.action[i], root_n=comp.root_n[i],
+                        root_v=comp.root_v[i], tree_size=comp.tree_size[i],
+                        dup_selections=np.float32(0.0), max_o=comp.max_o[i],
+                        overflowed=comp.overflowed[i], ticks=comp.ticks[i],
+                    )
+                    self._results[req_id] = row
+                    fresh[req_id] = row
+                    settle = first + int(comp.settle_tick[i])
+                    self._record(req_id, settle - int(comp.ticks[i]), settle,
+                                 t_end)
             admitted = staged - int(count_after)
             self._ring_free = self.ring_capacity - int(count_after)
             self._inflight += admitted - n
             self.stats.admissions += admitted
             self.stats.completed += n
-            self.stats.ticks += int(t)
+            self.stats.ticks += t
             self.stats.busy_tree_ticks += int(busy)
+            self.stats.attended_positions += int(att)
         self.stats.host_rounds += 1
-        self.stats.ring_occupancy_sum += staged
         return fresh
+
+    def _tick_time(self, tick: int) -> float:
+        """Host time of the start of master tick ``tick`` (or of the end of
+        the last segment run), by linear interpolation over the host
+        interval of the segment that ran it."""
+        i = bisect.bisect_right(self._segments, tick, key=lambda g: g[0]) - 1
+        first, n, t0, t1 = self._segments[i]
+        return t0 + (t1 - t0) * (tick - first) / n
+
+    def _record(self, req_id: int, admit: int, settle: int, answered: float):
+        submit, staged = self._times.pop(req_id)
+        rec = RequestTimeline(
+            submit=submit, staged=staged, admitted=self._tick_time(admit),
+            settled=self._tick_time(settle), answered=answered,
+            admit_tick=admit, settle_tick=settle,
+        )
+        self._timeline[req_id] = rec
+        self.stats.queue_wait_us += round((rec.admitted - submit) * 1e6)
+        self.stats.answer_wait_us += round((answered - rec.settled) * 1e6)
 
     def drain(self, max_rounds: int = 100_000) -> dict:
         """Poll until every submitted request has a result; return them all.
@@ -735,3 +809,9 @@ class SearchService:
     def results(self) -> dict:
         """All completed requests so far (``{req_id: SearchResult row}``)."""
         return dict(self._results)
+
+    @property
+    def timeline(self) -> dict:
+        """``{req_id: RequestTimeline}`` of every request answered on the
+        fused path so far."""
+        return dict(self._timeline)

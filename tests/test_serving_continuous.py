@@ -395,9 +395,12 @@ def test_ring_churn_zero_leaked_pages(tiny_lm):
     assert bool(jnp.all(svc._ring.aux["table"] == p))
     assert bool(jnp.all(svc._ring.aux["len"] == 0))
     assert int(svc._ring.count) == 0
-    # The fused path really ran: admissions all flowed through the ring.
+    # The fused path really ran: admissions all flowed through the ring,
+    # each request staged before its row took it.
     assert svc.stats.admissions == len(prompts)
-    assert svc.stats.ring_occupancy > 0.0
+    timeline = svc.timeline
+    assert sorted(timeline) == list(range(len(prompts)))
+    assert all(r.staged <= r.admitted for r in timeline.values())
 
 
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "host"])
