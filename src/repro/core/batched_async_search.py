@@ -56,7 +56,13 @@ import jax.numpy as jnp
 from ..envs.base import Environment
 from . import batched_tree as btree
 from .async_search import EXPAND, FREE, SIM, tick_snapshot
-from .evaluators import CATCH_UP, REFILL_CACHE, Evaluator, RolloutEvaluator
+from .evaluators import (
+    CATCH_UP,
+    REFILL_CACHE,
+    Evaluator,
+    RolloutEvaluator,
+    SlotColumn,
+)
 from .batched_search import (
     _canonical_keys,
     _expansion_actions,
@@ -312,9 +318,10 @@ class BatchedAsyncEngine:
                 t_launch = t_launch + want.astype(jnp.int32)
                 t_done = t_done + (want & is_term).astype(jnp.int32)
             # Re-sync the evaluator's slot caches: slot column j of every
-            # tree lives at flat row b·W + j of the aux pool.
+            # tree lives at flat row b·W + j of the aux pool, a strided
+            # column the evaluator slices rather than gathers.
             aux, hit = self.evaluator.refill_aux(
-                cfg, aux, bidx * W + j, parent_state,
+                cfg, aux, SlotColumn(j, W), parent_state,
                 want & jnp.logical_not(is_term),
             )
             fr_hits = fr_hits + hit.astype(jnp.int32)

@@ -37,7 +37,8 @@ implementations, so engines stay evaluator-agnostic and
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Callable, Optional
+import dataclasses
+from typing import Any, Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,66 @@ def _flat_slot_rows(rows, w: int) -> jax.Array:
     ).reshape(-1)
 
 
+@dataclasses.dataclass(frozen=True)
+class SlotColumn:
+    """Slot ``index`` of every tree: flat aux rows ``arange(N // width) *
+    width + index``, in that order.
+
+    The batched engine refills one column of its ``[B, W]`` slot grid at a
+    time.  The flat slot axis is tree-major, so the column is a strided
+    slice of a free ``[N // W, W]`` reshape: :func:`take_slots` reads it
+    with one dynamic slice and :func:`put_slots` writes it back in place.
+    The equivalent index array lowers on the TPU to a gather that first
+    slices the whole operand along its other axes (the whole KV cache, for
+    every refilled column).  ``index`` may be traced; ``width`` is static.
+    """
+
+    index: Any
+    width: int
+
+
+SlotRows = Union[jax.Array, SlotColumn]
+
+
+def _slot_grid(x, width: int, axis: int) -> jax.Array:
+    """``x`` with slot axis ``axis`` split into ``[N // width, width]``."""
+    n = x.shape[axis]
+    return x.reshape(x.shape[:axis] + (n // width, width) + x.shape[axis + 1:])
+
+
+def take_slots(x, rows: SlotRows, axis: int = 0) -> jax.Array:
+    """Rows ``rows`` of ``x``'s slot axis ``axis`` (an ``i32[R]`` index
+    array, or a :class:`SlotColumn`)."""
+    if isinstance(rows, SlotColumn):
+        return jax.lax.dynamic_index_in_dim(
+            _slot_grid(x, rows.width, axis), rows.index, axis + 1,
+            keepdims=False,
+        )
+    return x[(slice(None),) * axis + (rows,)]
+
+
+def put_slots(x, rows: SlotRows, y, axis: int = 0) -> jax.Array:
+    """``x`` with the rows :func:`take_slots` reads replaced by ``y``."""
+    if isinstance(rows, SlotColumn):
+        grid = _slot_grid(x, rows.width, axis)
+        y = jnp.expand_dims(jnp.asarray(y, x.dtype), axis + 1)
+        if x.ndim - axis > 2:
+            grid = jax.lax.dynamic_update_slice_in_dim(
+                grid, y, rows.index, axis + 1
+            )
+        else:
+            # The slot axis is one of the two minor axes the TPU tiles in
+            # memory; writing one row of every tile at a dynamic offset is
+            # slower there than rewriting the whole array under a mask.
+            hit = jnp.arange(rows.width) == rows.index
+            grid = jnp.where(
+                hit.reshape((rows.width,) + (1,) * (x.ndim - axis - 1)),
+                y, grid,
+            )
+        return grid.reshape(x.shape)
+    return x.at[(slice(None),) * axis + (rows,)].set(y)
+
+
 class Evaluator:
     """Protocol for environment/model evaluation inside a search engine.
 
@@ -127,10 +188,13 @@ class Evaluator:
       (``N = prod(prefix)``; ``root_states`` leaves lead with
       ``prefix[:-1]`` and broadcast over the trailing slot axis);
     * ``refill_aux(cfg, aux, rows, new_state, mask)`` — re-sync aux rows
-      ``rows`` (flat ``i32[R]`` indices) with the freshly assigned
-      ``new_state`` (leaves lead with ``[R]``) where ``mask`` holds.
-      Returns ``(aux, hits)`` where ``hits`` (``bool``, shaped like
-      ``rows``) flags rows served entirely from a speculative frontier
+      ``rows`` with the freshly assigned ``new_state`` (leaves lead with
+      ``[R]``) where ``mask`` (``bool[R]``) holds.  ``rows`` is either flat
+      ``i32[R]`` indices or a :class:`SlotColumn` (the batched engine's
+      slot ``j`` of every tree, ``R = N // W``); implementations read and
+      write rows only through :func:`take_slots` / :func:`put_slots` and
+      take ``R`` from ``mask``.  Returns ``(aux, hits)`` where ``hits``
+      (``bool[R]``) flags rows served entirely from a speculative frontier
       cache — no model forward dispatched (always ``False`` for evaluators
       without a frontier cache; the engines surface the count in trace
       mode as ``frontier_hits``);
@@ -161,8 +225,8 @@ class Evaluator:
         return ()
 
     def refill_aux(self, cfg, aux, rows, new_state, mask):
-        del cfg, new_state, mask
-        return aux, jnp.zeros(jnp.shape(rows), jnp.bool_)
+        del cfg, rows, new_state
+        return aux, jnp.zeros(jnp.shape(mask), jnp.bool_)
 
     def admit_aux(self, cfg, aux, rows, root_states, w):
         """Re-seed the slot caches of freshly admitted *tree* rows.
@@ -662,13 +726,15 @@ class CachedModelEvaluator(ModelEvaluator):
             if b == ():
                 return ()
             return {
-                "cache": jax.tree.map(lambda x: x[:, rows], b["cache"]),
-                "logits": b["logits"][rows],
+                "cache": jax.tree.map(
+                    lambda x: take_slots(x, rows, 1), b["cache"]
+                ),
+                "logits": take_slots(b["logits"], rows),
             }
 
         return {
-            "tokens": aux["tokens"][rows],
-            "len": aux["len"][rows],
+            "tokens": take_slots(aux["tokens"], rows),
+            "len": take_slots(aux["len"], rows),
             "pol": branch(aux["pol"]),
             "rew": branch(aux["rew"]),
         }
@@ -680,14 +746,15 @@ class CachedModelEvaluator(ModelEvaluator):
                 return ()
             return {
                 "cache": jax.tree.map(
-                    lambda x, y: x.at[:, rows].set(y), b["cache"], sb["cache"]
+                    lambda x, y: put_slots(x, rows, y, 1),
+                    b["cache"], sb["cache"],
                 ),
-                "logits": b["logits"].at[rows].set(sb["logits"]),
+                "logits": put_slots(b["logits"], rows, sb["logits"]),
             }
 
         return {
-            "tokens": aux["tokens"].at[rows].set(sub["tokens"]),
-            "len": aux["len"].at[rows].set(sub["len"]),
+            "tokens": put_slots(aux["tokens"], rows, sub["tokens"]),
+            "len": put_slots(aux["len"], rows, sub["len"]),
             "pol": branch(aux["pol"], sub["pol"]),
             "rew": branch(aux["rew"], sub["rew"]),
         }
@@ -794,7 +861,7 @@ class CachedModelEvaluator(ModelEvaluator):
     def refill_aux(self, cfg, aux, rows, new_state, mask):
         del cfg
         sub = self._take_rows(aux, rows)
-        r = rows.shape[0]
+        r = mask.shape[0]
         s_max = sub["tokens"].shape[-1]
         start, target, tokens, _ = self._rollback_targets(sub, new_state, mask)
         sub = dict(sub, tokens=tokens, len=start)
@@ -1111,12 +1178,15 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
         def branch(b):
             if b == ():
                 return ()
-            return {"k": b["k"], "v": b["v"], "logits": b["logits"][rows]}
+            return {
+                "k": b["k"], "v": b["v"],
+                "logits": take_slots(b["logits"], rows),
+            }
 
         return {
-            "tokens": aux["tokens"][rows],
-            "len": aux["len"][rows],
-            "table": aux["table"][rows],
+            "tokens": take_slots(aux["tokens"], rows),
+            "len": take_slots(aux["len"], rows),
+            "table": take_slots(aux["table"], rows),
             "refcount": aux["refcount"],
             "oom": aux["oom"],
             "pol": branch(aux["pol"]),
@@ -1130,13 +1200,13 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
                 return ()
             return {
                 "k": sb["k"], "v": sb["v"],
-                "logits": b["logits"].at[rows].set(sb["logits"]),
+                "logits": put_slots(b["logits"], rows, sb["logits"]),
             }
 
         return {
-            "tokens": aux["tokens"].at[rows].set(sub["tokens"]),
-            "len": aux["len"].at[rows].set(sub["len"]),
-            "table": aux["table"].at[rows].set(sub["table"]),
+            "tokens": put_slots(aux["tokens"], rows, sub["tokens"]),
+            "len": put_slots(aux["len"], rows, sub["len"]),
+            "table": put_slots(aux["table"], rows, sub["table"]),
             "refcount": sub["refcount"],
             "oom": sub["oom"],
             "pol": branch(aux["pol"], sub["pol"]),
@@ -1325,7 +1395,7 @@ class PagedCachedModelEvaluator(CachedModelEvaluator):
         from ..models import release_pages
 
         sub = self._take_rows(aux, rows)
-        r = rows.shape[0]
+        r = mask.shape[0]
         s_max = sub["tokens"].shape[-1]
         start, target, tokens, _ = self._rollback_targets(sub, new_state, mask)
         bs = self.block_size
@@ -1784,15 +1854,17 @@ class _FrontierMixin:
             if b == ():
                 return ()
             return {
-                "plog": b["plog"][rows], "clog": b["clog"][rows],
-                "ck": b["ck"][:, rows], "cv": b["cv"][:, rows],
+                "plog": take_slots(b["plog"], rows),
+                "clog": take_slots(b["clog"], rows),
+                "ck": take_slots(b["ck"], rows, 1),
+                "cv": take_slots(b["cv"], rows, 1),
             }
 
         sub["fr"] = {
-            "ptok": fr["ptok"][rows], "plen": fr["plen"][rows],
-            "valid": fr["valid"][rows], "cand": fr["cand"][rows],
-            "pol": br(fr["pol"]), "rew": br(fr["rew"]),
+            key: take_slots(fr[key], rows)
+            for key in ("ptok", "plen", "valid", "cand")
         }
+        sub["fr"].update(pol=br(fr["pol"]), rew=br(fr["rew"]))
         return sub
 
     @jax.named_scope(REFILL_CACHE)
@@ -1804,20 +1876,19 @@ class _FrontierMixin:
             if b == ():
                 return ()
             return {
-                "plog": b["plog"].at[rows].set(sb["plog"]),
-                "clog": b["clog"].at[rows].set(sb["clog"]),
-                "ck": b["ck"].at[:, rows].set(sb["ck"]),
-                "cv": b["cv"].at[:, rows].set(sb["cv"]),
+                "plog": put_slots(b["plog"], rows, sb["plog"]),
+                "clog": put_slots(b["clog"], rows, sb["clog"]),
+                "ck": put_slots(b["ck"], rows, sb["ck"], 1),
+                "cv": put_slots(b["cv"], rows, sb["cv"], 1),
             }
 
         out["fr"] = {
-            "ptok": fr["ptok"].at[rows].set(sfr["ptok"]),
-            "plen": fr["plen"].at[rows].set(sfr["plen"]),
-            "valid": fr["valid"].at[rows].set(sfr["valid"]),
-            "cand": fr["cand"].at[rows].set(sfr["cand"]),
-            "pol": br(fr["pol"], sfr["pol"]),
-            "rew": br(fr["rew"], sfr["rew"]),
+            key: put_slots(fr[key], rows, sfr[key])
+            for key in ("ptok", "plen", "valid", "cand")
         }
+        out["fr"].update(
+            pol=br(fr["pol"], sfr["pol"]), rew=br(fr["rew"], sfr["rew"])
+        )
         return out
 
     def admit_aux(self, cfg, aux, rows, root_states, w):
@@ -2039,7 +2110,7 @@ class FrontierModelEvaluator(_FrontierMixin, CachedModelEvaluator):
     def refill_aux(self, cfg, aux, rows, new_state, mask):
         del cfg
         sub = self._take_rows(aux, rows)
-        r = rows.shape[0]
+        r = mask.shape[0]
         s_max = sub["tokens"].shape[-1]
         idx = jnp.arange(r)
         start, target, tokens, common = self._rollback_targets(
@@ -2171,7 +2242,7 @@ class PagedFrontierModelEvaluator(_FrontierMixin, PagedCachedModelEvaluator):
         from ..models import release_pages
 
         sub = self._take_rows(aux, rows)
-        r = rows.shape[0]
+        r = mask.shape[0]
         s_max = sub["tokens"].shape[-1]
         idx = jnp.arange(r)
         start, target, tokens, common = self._rollback_targets(

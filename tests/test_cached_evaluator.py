@@ -12,7 +12,12 @@ Three claim families (ISSUE 5 satellite):
   and decodes only the divergent suffix;
 * **cache-depth invariant** — inside the real async engines (trace mode),
   every busy slot's ``cache['len']`` equals its token prefix length at
-  every master tick, across settle/refill.
+  every master tick, across settle/refill;
+* **column refill** — a refill addressed by a :class:`SlotColumn` (the
+  batched engine's slot ``j`` of every tree) is bit-identical to one
+  addressed by the equivalent index array, on every cached evaluator, and
+  the engine's master tick moves slot-cache rows by slice, never by a
+  gather or scatter over the whole cache.
 """
 
 import dataclasses
@@ -26,11 +31,15 @@ import pytest
 from repro.configs import get_reduced
 from repro.core import (
     CachedModelEvaluator,
+    FrontierModelEvaluator,
     ModelEvaluator,
+    PagedCachedModelEvaluator,
+    PagedFrontierModelEvaluator,
     SearchSpec,
     build_searcher,
 )
-from repro.core.evaluators import FREE, SIM
+from repro.core.batched_async_search import BatchedAsyncEngine
+from repro.core.evaluators import EXPAND, FREE, SIM, SlotColumn
 from repro.envs.token_env import TokenEnvState, make_token_env
 from repro.models import init_params
 
@@ -432,3 +441,127 @@ def test_cached_evaluator_rejects_recurrent_families():
     )
     with pytest.raises(ValueError, match="recurrent"):
         CachedModelEvaluator(cfg, {}, top_k=4)
+
+
+# ---------------------------------------------------------------------------
+# Column refill: SlotColumn rows equal index-array rows, and stay slices.
+# ---------------------------------------------------------------------------
+
+_PAGED = dict(block_size=4, num_blocks=96)
+COLUMN_EVALUATORS = {
+    "dense": lambda c, p: CachedModelEvaluator(c, p, top_k=4, eos_token=1),
+    "paged": lambda c, p: PagedCachedModelEvaluator(
+        c, p, top_k=4, eos_token=1, **_PAGED
+    ),
+    "frontier": lambda c, p: FrontierModelEvaluator(
+        c, p, top_k=4, eos_token=1
+    ),
+    "paged_frontier": lambda c, p: PagedFrontierModelEvaluator(
+        c, p, top_k=4, eos_token=1, **_PAGED
+    ),
+}
+
+
+def _tick_all(ev, scfg, state, aux, kind, act, seed):
+    n = state.length.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(seed), n)
+    (state, *_), aux = ev.tick(
+        scfg, jnp.full((n,), kind, jnp.int32), act, state,
+        jnp.zeros((n,), jnp.bool_), jnp.zeros((n,), jnp.float32),
+        jnp.ones((n,), jnp.float32), jnp.zeros((n,), jnp.int32), keys, aux,
+    )
+    return state, aux
+
+
+@pytest.mark.parametrize("j", [0, 2])
+@pytest.mark.parametrize("kind", sorted(COLUMN_EVALUATORS))
+def test_slot_column_refill_matches_index_rows(lm, kind, j):
+    """``refill_aux`` over ``SlotColumn(j, W)`` equals ``refill_aux`` over
+    ``arange(B) * W + j`` bit for bit: the whole returned aux and the hit
+    mask, with one row masked out and, for the frontier evaluators, a
+    parent hit and a child hit among the rest."""
+    cfg, params = lm
+    ev = COLUMN_EVALUATORS[kind](cfg, params)
+    scfg = _scfg()
+    b, w = 3, 4
+    n = b * w
+    roots = _ragged_states(lengths=(4, 6, 5))
+    aux = ev.init_aux(roots, (b, w))
+    parent = jax.tree.map(lambda x: jnp.repeat(x, w, axis=0), roots)
+    # Every slot expands its own child (the frontier snapshot), then rolls
+    # out one more token, so the rows of a column hold distinct caches.
+    child, aux = _tick_all(
+        ev, scfg, parent, aux, EXPAND, jnp.arange(n) % ev.top_k, seed=0
+    )
+    deeper, aux = _tick_all(
+        ev, scfg, child, aux, SIM, jnp.zeros((n,), jnp.int32), seed=1
+    )
+    rows = jnp.arange(b) * w + j
+    # Tree 0 goes back to its parent, tree 1 is masked out, tree 2 moves to
+    # its expanded child.
+    new_state = jax.tree.map(
+        lambda p_, d, c: jnp.stack([p_[rows[0]], d[rows[1]], c[rows[2]]]),
+        parent, deeper, child,
+    )
+    mask = jnp.asarray([True, False, True])
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def refill(aux, j, column):
+        at = SlotColumn(j, w) if column else jnp.arange(b) * w + j
+        return ev.refill_aux(scfg, aux, at, new_state, mask)
+
+    want = refill(aux, jnp.int32(j), False)
+    got = refill(aux, jnp.int32(j), True)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    if "frontier" in kind:
+        np.testing.assert_array_equal(np.asarray(got[1]), [True, False, True])
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def test_batched_refill_slices_the_slot_cache(lm):
+    """The batched engine's master tick (``BatchedAsyncEngine.step``) with
+    the dense cached evaluator reads and writes refill rows by slice: no
+    gather or scatter takes an operand shaped like the slot cache.  (On the
+    TPU a row gather first copies the whole operand, the whole KV cache per
+    refilled column.)"""
+    env, ev = _token_search_pieces(lm)
+    b = 3
+    spec = SearchSpec(
+        algo="wu_uct", engine="async", batch=b, num_simulations=12,
+        wave_size=4, max_depth=5, max_sim_steps=5, max_width=4, gamma=1.0,
+    )
+    engine = BatchedAsyncEngine(env, spec.config, b, evaluator=ev)
+    roots = jax.vmap(env.init)(jax.random.split(jax.random.PRNGKey(2), b))
+    carry = engine.init_carry(roots, jax.random.split(jax.random.PRNGKey(1), b))
+    cache = carry[-2]["pol"]["cache"]["kv"]["k"]
+    grid = cache.shape[:1] + (b, engine.W) + cache.shape[2:]
+    eqns = list(_eqns(jax.make_jaxpr(engine.step)(carry).jaxpr))
+
+    def shapes(eqn):
+        return [getattr(v.aval, "shape", None) for v in eqn.invars]
+
+    moved = [
+        e.primitive.name for e in eqns
+        if (e.primitive.name == "gather"
+            or e.primitive.name.startswith("scatter"))
+        and cache.shape in shapes(e)
+    ]
+    assert not moved, moved
+    # The refill's column slices are there: one write-back per cache leaf.
+    writes = [
+        e for e in eqns
+        if e.primitive.name == "dynamic_update_slice" and grid in shapes(e)
+    ]
+    assert len(writes) == 2, len(writes)

@@ -9,7 +9,8 @@ Qwen2.5-32B's widths — 40 query / 8 KV heads of 128, bf16 — and the shapes
 frontiers, 16-token pages.  The attention kernels are also compiled at the
 head widths of every other configured family (head dims of 64 and 112,
 groups of 1 to 16), and ``jax.grad`` of the loss at Qwen2.5-32B's widths,
-which runs the flash kernel forward.
+which runs the flash kernel forward.  The slot-cache refill is compiled at
+the deep-sessions cell's cache to check that it moves rows by slice.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers all
@@ -18,13 +19,16 @@ import this file.
 
 import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config, list_archs
+from repro.core.evaluators import CachedModelEvaluator, SlotColumn
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention_fwd,
     paged_decode_attention_fwd,
@@ -185,3 +189,90 @@ def test_loss_gradient_compiles_for_v5e(seq, one_chip, no_compile_cache,
     text = grad.lower(params, tokens).compile().as_text()
     jax.clear_caches()
     assert ("%flash_attention" in text) == (_flash_block(seq) is not None)
+
+
+_SHAPE = re.compile(
+    r"\b(?:bf16|f16|f32|s8|s16|s32|u8|u16|u32|pred)\[([\d,]*)\]"
+)
+_OPCODE = re.compile(r"\s([a-z][\w-]*)\(")
+IN_PLACE = {
+    "parameter", "get-tuple-element", "bitcast", "tuple", "while",
+    "dynamic-update-slice",
+}
+
+
+def _large_outputs(text, limit):
+    """``(instruction, opcode, root opcode of the computation it calls)`` of
+    every instruction outside a fusion whose output holds an array of more
+    than ``limit`` elements, from compiled HLO text."""
+    roots, fused, comp, found = {}, set(), None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{\s*$", line)
+        if head and " = " not in line:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"\s*(ROOT )?%(\S+) = (.*)$", line)
+        if not inst:
+            continue
+        rest = inst.group(3)
+        op = _OPCODE.search(" " + rest)
+        op = op.group(1) if op else ""
+        if inst.group(1):
+            roots[comp] = op
+        called = re.search(r"calls=%([\w.-]+)", rest)
+        called = called.group(1) if called else None
+        if op == "fusion":
+            fused.add(called)
+        dims = _SHAPE.findall(rest[: rest.find(op + "(")])
+        if any(math.prod(map(int, filter(None, d.split(",")))) > limit
+               for d in dims):
+            found.append((comp, inst.group(2), op, called))
+    return [
+        (name, op, roots.get(called))
+        for comp, name, op, called in found if comp not in fused
+    ]
+
+
+def test_refill_moves_slot_rows_by_slice_for_v5e(one_chip, no_compile_cache):
+    """The dense evaluator's refill at the deep-sessions cell's shapes (4
+    layers of ``[128, 384, 8, 128]`` bf16 K and V, 16 trees x 8 slots,
+    logits ``[128, 152064]``), one slot column at a time as the batched
+    engine runs it: the compiled program has no ``mini-gather-slice`` (the
+    TPU gather's copy of the whole operand) and no output larger than a
+    column of the cache other than an in-place ``dynamic-update-slice``."""
+    trees, width, max_len = 16, 8, 384
+    cfg = dataclasses.replace(get_config("qwen2.5-32b"), num_layers=4)
+    ev = CachedModelEvaluator(cfg, None, top_k=TOP_K)
+    n = trees * width
+
+    def s(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kv = s(cfg.num_layers, n, max_len, cfg.num_kv_heads, cfg.head_dim)
+    aux = {
+        "tokens": s(n, max_len, dtype=jnp.int32),
+        "len": s(n, dtype=jnp.int32),
+        "pol": {"cache": {"kv": {"k": kv, "v": kv}},
+                "logits": s(n, cfg.vocab_size)},
+        "rew": (),
+    }
+
+    def refill(aux):
+        def column(j, aux):
+            rows = SlotColumn(j, width)
+            sub = ev._take_rows(aux, rows)
+            sub = jax.tree.map(lambda x: x + jnp.ones((), x.dtype), sub)
+            return ev._put_rows(aux, rows, sub)
+
+        return jax.lax.fori_loop(0, width, column, aux)
+
+    text = jax.jit(refill, donate_argnums=0).lower(aux).compile().as_text()
+    assert "mini-gather-slice" not in text
+    large = _large_outputs(text, math.prod(kv.shape) // width)
+    assert any(op == "while" for _, op, _ in large)
+    copies = [
+        (name, op, root) for name, op, root in large
+        if op not in IN_PLACE
+        and not (op == "fusion" and root == "dynamic-update-slice")
+    ]
+    assert not copies, copies
