@@ -9,12 +9,12 @@ In one process, for each seed: a run of the cell exactly as
 load, the wait for late answers), then the numbers ``bench/checks.py``
 compares, through the harness's own comparison.  For the control seeds it
 also reads the control in the program's place, through the same
-comparison: the reference computed with float8 linear layers
-(``bench.reference``, ``quant="fp8"``) at the same slots and tokens, and
-the tree policy's rule computed in bfloat16 (``bench.tree_ref``) on the
-same trees; and two faults planted in the reference put in the program's
-place, a selection that always takes the first child it may and one that
-takes the worst value.  One JSON line per seed; the benchmark's own runs
+comparison: the reference computed with float8 linear layers (the
+architecture module's ``last_logits``, ``quant="fp8"``) at the same slots
+and tokens, and the tree policy's rule computed in bfloat16
+(``bench.tree_ref``) on the same trees; and two faults planted in the
+reference put in the program's place, a selection that always takes the
+first child it may and one that takes the worst value.  One JSON line per seed; the benchmark's own runs
 never run the control.  Exits non-zero without a TPU.
 """
 
@@ -53,7 +53,7 @@ def readings(cell: dict, seed: int, seconds: float, control: bool) -> dict:
         sample = served.sample
         ctl = harness.Readings(
             want=r.want,
-            logit_errs=reference.rel_l2(reference.last_logits(
+            logit_errs=reference.rel_l2(served.arch.last_logits(
                 served.weights, cell["config"], sample["tokens"],
                 sample["len"], quant="fp8"), r.want),
             select_gaps=tree_ref.select_gaps(

@@ -1,10 +1,11 @@
 """One run of one cell: set-up, the measured window, the checks, the line.
 
 Set-up (``setup_s``, from process start to the first timed request): the
-compile cache, the weights made on the device from the seed, the
-``SearchService`` of the cell's evaluator path, and a warm-up request that
-compiles (or loads) the ``stage`` and ``serve_segment`` programs the window
-drives.  The window then drives the cell's traffic through
+compile cache, the model the configuration's architecture module
+(``bench/archs/``) builds and its weights, made on the device from the
+seed, the ``SearchService`` of the cell's evaluator path, and a warm-up
+request that compiles (or loads) the ``stage`` and ``serve_segment``
+programs the window drives.  The window then drives the cell's traffic through
 ``SearchService.submit`` and ``poll`` on the fused ring for ``--seconds``.
 After it closes: the evaluator slots are sampled and the search trees
 copied with the program's selection at each of their nodes, every request
@@ -26,7 +27,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from . import checks, flops, reference, registry, system, trace, tree_ref
+from . import checks, reference, registry, system, trace, tree_ref
 from . import traffic as traffic_mod
 from .traffic import SPAN, Window
 
@@ -97,6 +98,7 @@ class Context:
     """What a per-layer metric reader sees of one traced run."""
 
     cell: dict
+    arch: object             # the configuration's bench/archs module
     stats: dict              # ServeStats counts over the window
     window_s: float
     flops: float
@@ -116,6 +118,7 @@ class Served:
     """What one run of a cell left for the checks and the metrics."""
 
     cell: dict
+    arch: object             # the configuration's bench/archs module
     weights: object
     setup_s: float
     window: Window
@@ -143,8 +146,9 @@ def serve(cell: dict, seed: int, seconds: float, traced: bool, *,
     """Set-up, the window and the wait for late answers; the service is
     freed before this returns, the weights are kept for the reference."""
     counter = CompileCounter()
-    cfg = system.model_config(cell["config"])
-    weights = system.make_weights(cfg, seed)
+    arch = registry.arch(cell["config"], root)
+    cfg = arch.model_config(cell["config"])
+    weights = getattr(arch, "make_weights", system.make_weights)(cfg, seed)
     jax.block_until_ready(weights)
     svc = system.build_service(cfg, weights, cell)
     keys = _keys(seed)
@@ -169,7 +173,9 @@ def serve(cell: dict, seed: int, seconds: float, traced: bool, *,
     # The slots and trees as the window left them; where no slot had decoded
     # two tokens since its refill (or every row had settled and released its
     # slots), or every tree had just been reset for a new request, as the
-    # first later poll that leaves one.
+    # first later poll that leaves one.  A later poll whose slots are no
+    # better (none decoded twice, or none live once the last rows settle)
+    # leaves the sample as it was.
     rng = np.random.default_rng([int(seed), 11])
     sample = system.slot_sample(svc, cell["check_slots"], rng)
     tree = system.tree_snapshot(svc)
@@ -177,7 +183,9 @@ def serve(cell: dict, seed: int, seconds: float, traced: bool, *,
     def resample():
         nonlocal sample, tree
         if not np.any(sample["steps"] >= 2):
-            sample = system.slot_sample(svc, cell["check_slots"], rng)
+            fresh = system.slot_sample(svc, cell["check_slots"], rng)
+            if np.any(fresh["steps"] >= 2) or not sample["slot"].size:
+                sample = fresh
         if not tree["inner_nodes"]:
             tree = system.tree_snapshot(svc)
 
@@ -186,7 +194,7 @@ def serve(cell: dict, seed: int, seconds: float, traced: bool, *,
     device = device_info(cell["chips"])
     del svc
     gc.collect()
-    return Served(cell, weights, setup_s, win, stats, sample, tree, late,
+    return Served(cell, arch, weights, setup_s, win, stats, sample, tree, late,
                   results, device, tr.planes, counter.count)
 
 
@@ -207,8 +215,8 @@ def read(served: Served) -> Readings:
         raise ValueError(f"bench/tree_ref.py states WU-UCT only, not "
                          f"{search['algo']!r}")
     s = served.sample
-    want = reference.last_logits(served.weights, cell["config"], s["tokens"],
-                                 s["len"])
+    want = served.arch.last_logits(served.weights, cell["config"],
+                                   s["tokens"], s["len"])
     return Readings(
         want=want, logit_errs=reference.rel_l2(s["logits"], want),
         select_gaps=tree_ref.select_gaps(served.tree, served.tree["acts"],
@@ -256,10 +264,9 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool, *,
         summary = trace.reduce(served.planes) if served.planes else None
         spec = cell["search"]
         ctx = Context(
-            cell=cell, stats=stats, window_s=window_s,
-            flops=flops.window_flops(
-                cell["config"], busy_tree_ticks=stats["busy_tree_ticks"],
-                wave=spec["wave_size"], admissions=stats["admissions"],
+            cell=cell, arch=served.arch, stats=stats, window_s=window_s,
+            flops=served.arch.window_flops(
+                cell["config"], stats, wave=spec["wave_size"],
                 max_len=cell["max_len"]),
             peak=peak_row(device["kind"], root) if require_chips else {},
             trace=summary,

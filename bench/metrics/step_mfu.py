@@ -1,7 +1,8 @@
-"""Model FLOPs of the window (``bench.flops.window_flops``: decode of every
-busy row's slots and the staging prefill at its padded length, 2 FLOPs per
-matmul parameter per token, attention left out) over the window's seconds
-and the chip's bf16 peak, in percent."""
+"""Model FLOPs of the window (the architecture module's ``window_flops``;
+for ``bench/archs/dense.py``: decode of every busy row's slots and the
+staging prefill at its padded length, 2 FLOPs per matmul parameter per
+token, attention left out) over the window's seconds and the chip's bf16
+peak, in percent."""
 
 
 def read(ctx):

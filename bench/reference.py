@@ -1,14 +1,14 @@
-"""Plain reference forward of the served model, and its lower-precision
-control.
+"""Float32 building blocks of the plain reference forwards, and the loop
+that runs one over blocks of rows.
 
-Written from the architecture the configuration file names (pre-norm
-decoder: RMSNorm, GQA attention with optional q/k/v bias and rotate-half
-rotary positions, SwiGLU MLP, untied LM head), in ``jax.numpy`` and
-float32 with ``highest`` matmul precision.  It imports nothing of the
-program; it reads the benchmark's own weights by their names in the
-parameter tree (``embed``, ``blocks.attn.wq`` ...).  It runs layer by layer
-over blocks of rows, so it fits beside the weights on one chip, and returns
-the next-token logits at each row's last position.
+Each architecture's reference (``bench/archs/<arch>.py``) is written from
+its configuration file with these: ``jax.numpy`` in float32 with
+``highest`` matmul precision.  They import nothing of the program, and read
+the benchmark's own weights by their names in the parameter tree
+(``embed``, ``final_norm``, ``lm_head`` ...).  :func:`by_row_blocks` runs
+an architecture's decoder stack layer by layer over blocks of rows, so it
+fits beside the weights on one chip, and returns the next-token logits at
+each row's last position.
 
 ``quant="fp8"`` is the control: every linear layer's operands rounded to
 float8 e4m3 with a scale per row of activations and per output column of
@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -30,25 +31,25 @@ HIGHEST = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0
 
 
-def _q8(x, axis):
+def q8(x, axis):
     scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / FP8_MAX
     scale = jnp.where(scale > 0, scale, 1.0)
     return (x / scale).astype(jnp.float8_e4m3fn).astype(F32) * scale
 
 
-def _linear(x, w, quant):
+def linear(x, w, quant):
     w = w.astype(F32)
     if quant == "fp8":
-        x, w = _q8(x, -1), _q8(w, 0)
+        x, w = q8(x, -1), q8(w, 0)
     return jnp.dot(x, w, precision=HIGHEST)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * scale.astype(F32)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate-half rotary embedding at positions 0..S-1; x [B, S, H, D]."""
     s, d = x.shape[1], x.shape[-1]
     freqs = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=F32) / d)
@@ -58,7 +59,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v, q_chunk):
+def attention(q, k, v, q_chunk):
     """Causal GQA attention; q [B, S, Hq, D], k/v [B, S, Hkv, D].  Query
     head h reads KV head h // (Hq / Hkv)."""
     b, s, hq, d = q.shape
@@ -81,41 +82,6 @@ def _attention(q, k, v, q_chunk):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, hq * d)
 
 
-@functools.partial(jax.jit, static_argnames=("arch", "quant", "q_chunk"))
-def _layer(x, blocks, i, *, arch, quant, q_chunk):
-    hq, hkv, d, theta, eps, bias = arch
-    p = jax.tree.map(lambda a: a[i], blocks)
-    a = p["attn"]
-    b, s, _ = x.shape
-    h = _rms(x, p["attn_norm"], eps)
-    q, k, v = (_linear(h, a[w], quant) for w in ("wq", "wk", "wv"))
-    if bias:
-        q, k, v = q + a["bq"].astype(F32), k + a["bk"].astype(F32), \
-            v + a["bv"].astype(F32)
-    q = _rope(q.reshape(b, s, hq, d), theta)
-    k = _rope(k.reshape(b, s, hkv, d), theta)
-    v = v.reshape(b, s, hkv, d)
-    x = x + _linear(_attention(q, k, v, q_chunk), a["wo"], quant)
-    m = p["mlp"]
-    h = _rms(x, p["mlp_norm"], eps)
-    # SwiGLU over chunks of the hidden width, so no whole float32 copy of a
-    # projection is ever live.
-    ff = m["w_gate"].shape[1]
-    f_chunk = _divisor(ff, 4096)
-
-    def mlp(j, acc):
-        cols = functools.partial(jax.lax.dynamic_slice_in_dim,
-                                 start_index=j * f_chunk,
-                                 slice_size=f_chunk, axis=1)
-        up = jax.nn.silu(_linear(h, cols(m["w_gate"]), quant)) * _linear(
-            h, cols(m["w_up"]), quant)
-        down = jax.lax.dynamic_slice_in_dim(m["w_down"], j * f_chunk,
-                                            f_chunk, axis=0)
-        return acc + _linear(up, down, quant)
-
-    return jax.lax.fori_loop(0, ff // f_chunk, mlp, x)
-
-
 @jax.jit
 def _embed(embed, tokens):
     return embed[tokens].astype(F32)
@@ -123,45 +89,46 @@ def _embed(embed, tokens):
 
 @functools.partial(jax.jit, static_argnames=("eps", "quant", "v_chunk"))
 def _head(x, last, norm, head, *, eps, quant, v_chunk):
-    h = _rms(jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], norm,
-             eps)
+    h = rms(jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0], norm,
+            eps)
     def cols(j):
         w = jax.lax.dynamic_slice_in_dim(head, j * v_chunk, v_chunk, axis=1)
-        return _linear(h, w, quant)
+        return linear(h, w, quant)
 
     out = jax.lax.map(cols, jnp.arange(head.shape[1] // v_chunk))
     return jnp.moveaxis(out, 0, 1).reshape(h.shape[0], -1)
 
 
-def _divisor(n: int, at_most: int) -> int:
+def divisor(n: int, at_most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``at_most``."""
     c = max(1, min(n, at_most))
     while n % c:
         c -= 1
     return c
 
 
-def last_logits(weights, config: dict, tokens, lengths, *, quant=None,
-                tokens_per_block: int = 4096) -> np.ndarray:
+def by_row_blocks(weights, config: dict, tokens, lengths,
+                  stack: Callable, *, quant, tokens_per_block: int
+                  ) -> np.ndarray:
     """Logits ``[n, V]`` (float32) after each row's ``lengths[i]`` tokens.
 
     ``tokens`` is ``[n, S]``; positions past a row's length do not reach
     its logits (causal attention), so rows keep the program's fixed width
-    ``S`` and every call compiles one shape.
+    ``S`` and every call compiles one shape.  ``stack(x, q_chunk)`` applies
+    the architecture's decoder layers to a block of embedded rows ``x``
+    ``[rows, S, d]``, with attention over query chunks of ``q_chunk``;
+    the embedding, the final norm and the LM head are applied here.
     """
     tokens = np.asarray(tokens, np.int32)
     lengths = np.asarray(lengths, np.int32)
     n, s = tokens.shape
     rows = max(1, tokens_per_block // s)
-    arch = (config["num_attention_heads"], config["num_key_value_heads"],
-            config["head_dim"], float(config["rope_theta"]),
-            float(config["rms_norm_eps"]), bool(config["qkv_bias"]))
     eps = float(config["rms_norm_eps"])
     # Attention scores of one query chunk stay near 2**27 floats (512 MiB).
-    q_chunk = _divisor(
+    q_chunk = divisor(
         s, max(1, 2 ** 27 // (rows * config["num_attention_heads"] * s))
     )
-    v_chunk = _divisor(config["vocab_size"], 16384)
-    layers = config["num_hidden_layers"]
+    v_chunk = divisor(config["vocab_size"], 16384)
     out = []
     for lo in range(0, n, rows):
         tok = tokens[lo: lo + rows]
@@ -170,10 +137,7 @@ def last_logits(weights, config: dict, tokens, lengths, *, quant=None,
         if pad:
             tok = np.concatenate([tok, np.repeat(tok[-1:], pad, 0)])
             last = np.concatenate([last, np.repeat(last[-1:], pad)])
-        x = _embed(weights["embed"], jnp.asarray(tok))
-        for i in range(layers):
-            x = _layer(x, weights["blocks"], jnp.int32(i), arch=arch,
-                       quant=quant, q_chunk=q_chunk)
+        x = stack(_embed(weights["embed"], jnp.asarray(tok)), q_chunk)
         logits = _head(x, jnp.asarray(last), weights["final_norm"],
                        weights["lm_head"], eps=eps, quant=quant,
                        v_chunk=v_chunk)

@@ -1,11 +1,13 @@
 """Find the benchmark's parts by the names ``BENCHMARK.json`` gives them.
 
 Every lookup takes the checkout root, so the tests can point it at a copy
-that holds extra cells, metrics or loops added as files alone.
+that holds extra cells, metrics, loops or architectures added as files
+alone.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -60,7 +62,8 @@ def cell(name: str, root: Path = ROOT) -> dict:
 
 
 def module(kind: str, name: str, root: Path = ROOT):
-    """The module ``bench/<kind>/<name>.py``: a metric reader or a loop."""
+    """The module ``bench/<kind>/<name>.py``: a metric reader, a loop or an
+    architecture."""
     path = Path(root) / "bench" / kind / f"{name}.py"
     if not path.is_file():
         raise KeyError(f"no {kind} module for {name!r} at {path}")
@@ -70,6 +73,19 @@ def module(kind: str, name: str, root: Path = ROOT):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def arch(config: dict, root: Path = ROOT):
+    """The architecture module ``bench/archs/<arch>.py`` that a
+    configuration file names under ``"arch"`` (``dense`` where it names
+    none).  Loaded once a process for each root, so its jitted reference
+    compiles once however many runs the process makes."""
+    return _arch(Path(root).resolve(), config.get("arch", "dense"))
+
+
+@functools.lru_cache(maxsize=None)
+def _arch(root: Path, name: str):
+    return module("archs", name, root)
 
 
 def metric_reader(name: str, root: Path = ROOT) -> Callable:

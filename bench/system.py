@@ -1,9 +1,11 @@
 """The system under test: a ``SearchService`` built from a cell's files.
 
 This is the only module of the benchmark that imports the program
-(``repro``).  The weights are the benchmark's own, made here from the seed
-in the layout the program's ``init_params`` declares, so the reference can
-use them without taking anything the program made.
+(``repro``); an architecture's module (``bench/archs/``) builds the
+program's ``ModelConfig`` through it.  The weights are the benchmark's own,
+made here from the seed in the layout the program's ``init_params``
+declares, so the reference can use them without taking anything the
+program made.
 """
 
 from __future__ import annotations
@@ -26,26 +28,6 @@ DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 #: Standard deviation of every random matrix and bias (the program's own
 #: initializer uses the same).
 WEIGHT_STD = 0.02
-
-
-def model_config(c: dict) -> ModelConfig:
-    """The program's ``ModelConfig`` for a configuration file."""
-    return ModelConfig(
-        name=c["name"],
-        family="dense",
-        num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"],
-        qkv_bias=c["qkv_bias"],
-        rope_theta=float(c["rope_theta"]),
-        rms_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=c["tie_word_embeddings"],
-        dtype=DTYPES[c["torch_dtype"]],
-    )
 
 
 def seed_key(seed: int) -> jax.Array:

@@ -85,6 +85,29 @@ def test_toy_cell_runs_correct(toy_root, evaluator):
     assert all(" limit " in t for t in tail)
 
 
+def test_a_later_poll_with_no_live_slot_keeps_the_sample(toy_root,
+                                                         monkeypatch):
+    """Where the window's sample has no slot that decoded twice, later polls
+    are looked at for one; once the last rows settle and release their
+    slots a poll finds none live, and the sample the window left stays."""
+    add_cell(toy_root, "toy.dense", cell=dict(TOY_CELL))
+    real = system.slot_sample
+    sizes = []
+
+    def sample(svc, k, rng):
+        s = real(svc, k, rng)
+        sizes.append(s["slot"].size)
+        if len(sizes) == 1:
+            return dict(s, steps=np.zeros_like(s["steps"]))
+        return {key: v[:0] for key, v in s.items()}
+
+    monkeypatch.setattr(system, "slot_sample", sample)
+    line, err = _run(toy_root, "toy.dense")
+    assert sizes[0] > 0 and len(sizes) > 1
+    assert line["correct"] is True, err[-2000:]
+    assert line["checks"]["logit_rel_err"]["value"] < 1e-3
+
+
 def test_cell_and_metric_added_as_files_alone(toy_root):
     (toy_root / "bench" / "metrics" / "toy_admissions.py").write_text(
         "def read(ctx):\n    return float(ctx.stats['admissions'])\n")
@@ -252,7 +275,7 @@ def test_control_fails_where_the_program_passes(toy_root):
     s, tree, beta = served.sample, served.tree, c["search"]["beta"]
     control = harness.Readings(
         want=r.want,
-        logit_errs=reference.rel_l2(reference.last_logits(
+        logit_errs=reference.rel_l2(served.arch.last_logits(
             served.weights, c["config"], s["tokens"], s["len"], quant="fp8"),
             r.want),
         select_gaps=tree_ref.select_gaps(
@@ -317,13 +340,14 @@ def test_reference_matches_the_programs_forward_in_f32():
     from bench_toy import TOY_CONFIG
 
     conf = dict(TOY_CONFIG, num_hidden_layers=2)
-    cfg = system.model_config(conf)
+    arch = registry.arch(conf)
+    cfg = arch.model_config(conf)
     weights = system.make_weights(cfg, 3)
     rng = np.random.default_rng(0)
     tokens = rng.integers(1, 256, size=(3, 24)).astype(np.int32)
     lens = np.array([5, 17, 24], np.int32)
-    want = reference.last_logits(weights, conf, tokens, lens,
-                                 tokens_per_block=48)
+    want = arch.last_logits(weights, conf, tokens, lens,
+                            tokens_per_block=48)
     with jax.default_matmul_precision("highest"):
         full, _ = forward(weights, cfg, {"tokens": jnp.asarray(tokens)})
     got = np.asarray(full)[np.arange(3), lens - 1]

@@ -127,9 +127,9 @@ def _context(stats, kernel_s=None, cell=None):
         summary = trace.Summary(window_s=1.0, busy_s=1.0, chips=1,
                                 kernel_s=kernel_s, device_ops=[],
                                 idle_gaps=[])
+    cell = cell or registry.cell("qwen2.5-32b-l4.deep-sessions")
     return harness.Context(
-        cell=cell or registry.cell("qwen2.5-32b-l4.deep-sessions"),
-        stats=stats,
+        cell=cell, arch=registry.arch(cell["config"]), stats=stats,
         window_s=1.0, flops=0.0,
         peak={"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
         trace=summary)
@@ -181,7 +181,7 @@ def test_load_keeps_the_programs_host_spans(toy_root, tmp_path):
     from bench import system
 
     c = registry.cell("toy.sessions", toy_root)
-    cfg = system.model_config(c["config"])
+    cfg = registry.arch(c["config"], toy_root).model_config(c["config"])
     svc = system.build_service(cfg, system.make_weights(cfg, 1), c)
     svc.submit([5, 6, 7])
     svc.poll()                                  # compile outside the trace
